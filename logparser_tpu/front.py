@@ -874,6 +874,16 @@ class FrontTier:
         for a in self._sidecar_addresses:
             parse_sidecar_address(a)
         n_sidecars = max(n_sidecars, len(self._sidecar_addresses))
+        # One chip per spawned sidecar (logparser_tpu/chips.py): the k-th
+        # spawned slot is pinned to the host's k-th chip for every
+        # respawn and roll (the old process is reaped first); a lone
+        # sidecar keeps the host's chips.  More spawned sidecars than
+        # chips fails here, not in a child.
+        from .chips import assign_chips
+
+        self._chip_envs = assign_chips(
+            0 if spawner is not None
+            else n_sidecars - len(self._sidecar_addresses))
         self.supervisor = FrontSupervisor(self.policy, n_sidecars)
         # The supervisor is a PURE machine; the fleet serializes every
         # consultation (session threads + the prober race otherwise —
@@ -978,7 +988,13 @@ class FrontTier:
         return ProcessSidecar(
             index, host=self._host, extra_args=self._sidecar_args,
             ready_timeout_s=self.policy.ready_timeout_s,
+            env=self.sidecar_env(index),
         )
+
+    def sidecar_env(self, index: int) -> Dict[str, str]:
+        """Environment overrides of the sidecar spawned for slot
+        ``index``: its chip, or nothing on a host without chips."""
+        return self._chip_envs[index - len(self._sidecar_addresses)]
 
     def _warm(self, handle: Any) -> None:
         if self._warmup_fn is None:
@@ -1745,7 +1761,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
                     help="persistent compile-cache directory "
                          "(docs/COMPILE.md) — exported as "
-                         "LOGPARSER_TPU_COMPILE_CACHE to every spawned "
+                         "JAX_COMPILATION_CACHE_DIR to every spawned "
                          "sidecar, so respawns and rolling restarts warm "
                          "up by DESERIALIZING cached executables instead "
                          "of recompiling")
@@ -1759,9 +1775,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # Spawned sidecars inherit the front's environment (ProcessSidecar
         # copies os.environ), so one export here covers the whole fleet —
         # including every future respawn and rolling-restart replacement.
-        from .tpu.compile_cache import ENV_CACHE_DIR
-
-        os.environ[ENV_CACHE_DIR] = args.compile_cache
+        # (set directly: the tpu package would import JAX here).
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = args.compile_cache
     logging.basicConfig(
         level=getattr(logging, str(args.log_level).upper(), logging.INFO),
         format="%(asctime)s %(levelname)s %(name)s: %(message)s",

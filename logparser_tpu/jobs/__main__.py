@@ -95,7 +95,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
                          "(docs/COMPILE.md): resumed/repeated jobs and "
                          "pod hosts deserialize cached executables "
                          "instead of recompiling "
-                         "(= LOGPARSER_TPU_COMPILE_CACHE)")
+                         "(exported as JAX_COMPILATION_CACHE_DIR)")
     ap.add_argument("--stop-after-shards", type=int, default=None,
                     help=argparse.SUPPRESS)  # crash-drill hook (smoke)
     return ap
@@ -104,11 +104,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_arg_parser().parse_args(argv)
     if args.compile_cache:
-        import os
+        from ..tpu.compile_cache import set_cache_root
 
-        from ..tpu.compile_cache import ENV_CACHE_DIR
-
-        os.environ[ENV_CACHE_DIR] = args.compile_cache
+        set_cache_root(args.compile_cache)
     # SIGTERM = the cloud-TPU preemption notice: finish/commit the
     # current shard boundary, exit EXIT_PREEMPTED (resumable — cheaper
     # than the SIGKILL path by exactly one replayed shard).  An

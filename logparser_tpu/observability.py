@@ -178,7 +178,7 @@ def profile(log_dir: str) -> Iterator[None]:
 # ---------------------------------------------------------------------------
 
 # Wall-time buckets (seconds) sized for batch-stage latencies: sub-ms host
-# stages up through multi-second tunneled transfers.  +Inf is implicit.
+# stages up through multi-second transfers and compiles.  +Inf is implicit.
 DEFAULT_TIME_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -371,6 +371,11 @@ class MetricsRegistry:
     def get(self, name: str, labels: Optional[Dict[str, str]] = None) -> float:
         with self._lock:
             return self._counters.get((name, _labels_key(labels)), 0)
+
+    def total(self, name: str) -> float:
+        """Counter ``name`` summed over all its label sets."""
+        with self._lock:
+            return sum(v for (n, _), v in self._counters.items() if n == name)
 
     # -- gauges ----------------------------------------------------------
 

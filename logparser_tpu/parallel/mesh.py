@@ -224,10 +224,7 @@ def _sp_program_body(program: DeviceProgram, l_total: int, axis: str,
 def sequence_parallel_runner(program: DeviceProgram, mesh: Mesh, l_total: int):
     """jitted fn(buf [B, L], lengths [B]) with B sharded over 'data' and L
     sharded over 'seq'; per-op global resolution via pmin/psum collectives."""
-    try:
-        from jax import shard_map  # jax >= 0.6 public export
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     body = functools.partial(_sp_program_body, program, l_total, "seq")
     mapped = shard_map(

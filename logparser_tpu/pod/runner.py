@@ -219,8 +219,12 @@ def host_argv(spec: PodSpec, host_index: int,
 
 
 def _launch_host(spec: PodSpec, host_index: int, policy: PodPolicy,
-                 traceparent: Optional[str] = None) -> subprocess.Popen:
+                 traceparent: Optional[str] = None,
+                 chip_env: Optional[Dict[str, str]] = None
+                 ) -> subprocess.Popen:
     env = dict(os.environ)
+    if chip_env:
+        env.update(chip_env)
     if spec.host_env:
         env.update(spec.host_env)
     if traceparent:
@@ -298,6 +302,13 @@ def run_pod(spec: PodSpec, policy: Optional[PodPolicy] = None,
     policy = policy or PodPolicy()
     if spec.n_hosts < 1:
         raise ValueError(f"n_hosts must be positive, got {spec.n_hosts}")
+    # Subprocess hosts share this machine's chips: one chip per host
+    # (logparser_tpu/chips.py), refused before anything starts when there
+    # are fewer chips than hosts.  A 1-host pod keeps every chip, so its
+    # data_parallel mesh spans the machine.
+    from ..chips import assign_chips
+
+    chip_envs = [] if policy.inline else assign_chips(spec.n_hosts)
     from ..tools.chaos import ChaosSpec, PodChaos
 
     if chaos is None:
@@ -364,7 +375,8 @@ def run_pod(spec: PodSpec, policy: Optional[PodPolicy] = None,
                 procs[i] = _launch_host(
                     spec, i, policy,
                     traceparent=(h_span.traceparent
-                                 if h_span is not None else None))
+                                 if h_span is not None else None),
+                    chip_env=chip_envs[i])
                 after = preempt_plan.pop(i, None)
                 if after is not None:
                     threading.Thread(

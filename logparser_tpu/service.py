@@ -531,9 +531,10 @@ class _PrewarmWorker:
     Every freshly built parser is walked up the bucket ladder — including
     the coalesced-batch shape when continuous batching is on — on ONE
     daemon thread, so no request ever waits on a compile for a bucket it
-    did not itself need first.  With ``LOGPARSER_TPU_COMPILE_CACHE`` set,
-    each rung is a disk deserialize (or an in-memory no-op) instead of an
-    XLA compile; the per-rung source lands in
+    did not itself need first.  With a warm compile cache
+    (``compile_cache.cache_root()``), each rung is a disk deserialize (or
+    an in-memory no-op) instead of an XLA compile; the per-rung source
+    lands in
     ``parser_prewarm_shapes_total{source=memory|disk|compiled}``.
 
     Env knobs:
@@ -1561,6 +1562,14 @@ class ParseService:
             # every session — never let that spelling through.
             return float(v) if v and v > 0 else None
 
+        # Load pyarrow on THIS thread, before any session thread does:
+        # pyarrow 25's default (mimalloc) pool segfaults later
+        # allocations when pyarrow was first imported on a thread that
+        # has since exited — every sidecar crashed on its second request.
+        try:
+            import pyarrow  # noqa: F401
+        except ImportError:  # optional extra: row tables need it, not
+            pass             # the service itself
         defaults = ServiceLimits()
         if coalesce is None:
             # Env kill switch (docs/SERVICE.md): continuous batching is

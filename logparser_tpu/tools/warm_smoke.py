@@ -2,7 +2,7 @@
 (docs/COMPILE.md acceptance drill).
 
 Boots a REAL sidecar process twice against one persistent compile-cache
-directory (``LOGPARSER_TPU_COMPILE_CACHE``):
+directory (``JAX_COMPILATION_CACHE_DIR``):
 
 1. **Cold boot** — empty cache: the first request pays lower + compile
    and the background prewarmer walks the bucket ladder (including the
@@ -27,8 +27,8 @@ import os
 import re
 import socket
 import struct
+import shutil
 import sys
-import tempfile
 import time
 import urllib.request
 from typing import Any, Dict, List, Optional, Sequence
@@ -121,7 +121,9 @@ def boot_probe(cache_dir: str, *, lines: Sequence[str],
 
     from logparser_tpu.front import ProcessSidecar
 
-    env = {"LOGPARSER_TPU_COMPILE_CACHE": cache_dir}
+    from ..tpu.compile_cache import ENV_CACHE_DIR
+
+    env = {ENV_CACHE_DIR: cache_dir}
     if prewarm_buckets is not None:
         env["LOGPARSER_TPU_PREWARM_BUCKETS"] = prewarm_buckets
     if prewarm_line_len is not None:
@@ -192,58 +194,63 @@ def main() -> int:
     problems: List[str] = []
     lines = make_lines(DRILL_FORMAT, DRILL_LINES, seed=7)
     t_all = time.monotonic()
-    with tempfile.TemporaryDirectory(prefix="lptpu-warm-smoke-") as cache:
-        cold = boot_probe(cache, lines=lines)
-        print(f"warm-smoke: cold boot ready {cold['ready_s']:.1f}s, "
-              f"first request {cold['first_request_s']:.1f}s, "
-              f"counters {cold['counters']}")
-        if cold["counters"]["compile"] < 1:
-            problems.append(
-                "cold boot compiled nothing — the cache was not empty "
-                "or the AOT path is not engaged")
-        if not cold["prewarm_done"]:
-            problems.append(
-                "cold boot: background prewarm never completed "
-                f"(errors={cold['counters']['prewarm_errors']})")
+    # A fixed, emptied directory under the cache root: the cold boot
+    # must really be cold.
+    from logparser_tpu.tpu.compile_cache import cache_root
 
-        warm = boot_probe(cache, lines=lines)
-        print(f"warm-smoke: warm boot ready {warm['ready_s']:.1f}s, "
-              f"first request {warm['first_request_s']:.1f}s, "
-              f"counters {warm['counters']}")
-        c = warm["counters"]
-        # THE gate: a warm boot compiles nothing — counter-asserted,
-        # deserialize is the only phase allowed to move.
-        if c["lower"] or c["compile"]:
-            problems.append(
-                f"warm boot compiled: lower={c['lower']:.0f} "
-                f"compile={c['compile']:.0f} (must both be 0)")
-        if c["deserialize"] < 1:
-            problems.append("warm boot deserialized nothing — the "
-                            "first request did not come from the cache")
-        if not warm["prewarm_done"]:
-            problems.append(
-                "warm boot: background prewarm never completed "
-                f"(errors={c['prewarm_errors']})")
-        # Ladder coverage incl. the coalesced-batch shape: the default
-        # ladder (DEFAULT_BUCKET_LADDER) + the coalesce_max_lines bucket
-        # — all served from cache/memory, none compiled.
-        from logparser_tpu.service import ServiceLimits
-        from logparser_tpu.tpu.compile_cache import DEFAULT_BUCKET_LADDER
-        expect = len(set(DEFAULT_BUCKET_LADDER)
-                     | {ServiceLimits().coalesce_max_lines})
-        if c["prewarm_shapes"] < expect:
-            problems.append(
-                f"warm boot prewarm covered {c['prewarm_shapes']:.0f} "
-                f"shapes < {expect} (coalesced shape missing?)")
-        if c["prewarm_compiled"]:
-            problems.append(
-                f"warm boot prewarm COMPILED "
-                f"{c['prewarm_compiled']:.0f} shapes (must load them)")
-        if warm["arrow"] != cold["arrow"]:
-            problems.append("ARROW payload differs between cold and "
-                            "warm boot (cache served a wrong kernel?)")
-        expo_problems = validate_exposition(warm["exposition"])
-        problems += [f"exposition: {p}" for p in expo_problems]
+    cache = os.path.join(cache_root(), "warm-smoke")
+    shutil.rmtree(cache, ignore_errors=True)
+    cold = boot_probe(cache, lines=lines)
+    print(f"warm-smoke: cold boot ready {cold['ready_s']:.1f}s, "
+          f"first request {cold['first_request_s']:.1f}s, "
+          f"counters {cold['counters']}")
+    if cold["counters"]["compile"] < 1:
+        problems.append(
+            "cold boot compiled nothing — the cache was not empty "
+            "or the AOT path is not engaged")
+    if not cold["prewarm_done"]:
+        problems.append(
+            "cold boot: background prewarm never completed "
+            f"(errors={cold['counters']['prewarm_errors']})")
+
+    warm = boot_probe(cache, lines=lines)
+    print(f"warm-smoke: warm boot ready {warm['ready_s']:.1f}s, "
+          f"first request {warm['first_request_s']:.1f}s, "
+          f"counters {warm['counters']}")
+    c = warm["counters"]
+    # THE gate: a warm boot compiles nothing — counter-asserted,
+    # deserialize is the only phase allowed to move.
+    if c["lower"] or c["compile"]:
+        problems.append(
+            f"warm boot compiled: lower={c['lower']:.0f} "
+            f"compile={c['compile']:.0f} (must both be 0)")
+    if c["deserialize"] < 1:
+        problems.append("warm boot deserialized nothing — the "
+                        "first request did not come from the cache")
+    if not warm["prewarm_done"]:
+        problems.append(
+            "warm boot: background prewarm never completed "
+            f"(errors={c['prewarm_errors']})")
+    # Ladder coverage incl. the coalesced-batch shape: the default
+    # ladder (DEFAULT_BUCKET_LADDER) + the coalesce_max_lines bucket
+    # — all served from cache/memory, none compiled.
+    from logparser_tpu.service import ServiceLimits
+    from logparser_tpu.tpu.compile_cache import DEFAULT_BUCKET_LADDER
+    expect = len(set(DEFAULT_BUCKET_LADDER)
+                 | {ServiceLimits().coalesce_max_lines})
+    if c["prewarm_shapes"] < expect:
+        problems.append(
+            f"warm boot prewarm covered {c['prewarm_shapes']:.0f} "
+            f"shapes < {expect} (coalesced shape missing?)")
+    if c["prewarm_compiled"]:
+        problems.append(
+            f"warm boot prewarm COMPILED "
+            f"{c['prewarm_compiled']:.0f} shapes (must load them)")
+    if warm["arrow"] != cold["arrow"]:
+        problems.append("ARROW payload differs between cold and "
+                        "warm boot (cache served a wrong kernel?)")
+    expo_problems = validate_exposition(warm["exposition"])
+    problems += [f"exposition: {p}" for p in expo_problems]
 
     wall = time.monotonic() - t_all
     if problems:

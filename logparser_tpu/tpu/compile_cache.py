@@ -12,9 +12,11 @@ effect:
   so compile cost is attributable (``parser_compile_seconds_total{phase}``)
   and the compiled object is serializable
   (``jax.experimental.serialize_executable``).
-- :class:`CompileCache` is the content-addressed on-disk store
-  (``LOGPARSER_TPU_COMPILE_CACHE`` dir).  Keys hash the parser program
-  fingerprint, the (B, L) shape bucket, and the backend/jax version —
+- :class:`CompileCache` is the content-addressed on-disk store under
+  :func:`cache_root` (``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``), beside JAX's own persistent cache.  Keys
+  hash the parser program fingerprint, the (B, L) shape bucket, and the
+  backend/jax version —
   a mismatch on ANY component is a miss and a fresh compile, never a
   wrong kernel.  The host oracle stays the exactness referee regardless:
   a cache bug can cost a compile, not a byte of output.
@@ -45,7 +47,16 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-ENV_CACHE_DIR = "LOGPARSER_TPU_COMPILE_CACHE"
+# The ONE compile-cache setting: JAX's own persistent cache reads it at
+# import, and the executable store lives in a subdirectory of it.  Unset,
+# both go to a fixed path inside the checkout (a directory that moves
+# between runs never hits).
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+_STORE_SUBDIR = "logparser-executables"
 
 # Entry format version: bump when the on-disk layout changes.  Old entries
 # then simply miss (refused by magic), they are never misread.
@@ -55,6 +66,35 @@ _ENTRY_MAGIC = b"LPTPU-EXEC-v1\n"
 # buckets serving traffic actually hits (service chunks, feeder chunks,
 # coalesced batches all pad to powers of two >= 64).
 DEFAULT_BUCKET_LADDER = (64, 256, 1024)
+
+
+def cache_root() -> str:
+    """The compile-cache directory: ``JAX_COMPILATION_CACHE_DIR`` when set
+    (re-read per call, so CLIs and tests can repoint it), else the fixed
+    in-checkout default."""
+    return os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
+
+
+def set_cache_root(path: str) -> None:
+    """Place every compile cache of this process and its children under
+    ``path`` (the ``--compile-cache`` flags).  Exported, so spawned
+    children inherit it; JAX itself is only touched when this process
+    already imported it (a front tier never does)."""
+    import sys
+
+    os.environ[ENV_CACHE_DIR] = path
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_compilation_cache_dir", path)
+
+
+def configure_jax_cache() -> None:
+    """Point JAX's persistent compilation cache at :func:`cache_root` when
+    nothing has placed it yet.  With ``JAX_COMPILATION_CACHE_DIR`` set at
+    import JAX already did, and this sets nothing."""
+    import jax
+
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", cache_root())
 
 
 # ---------------------------------------------------------------------------
@@ -89,6 +129,7 @@ def backend_fingerprint() -> str:
     """jax/jaxlib version + backend platform + device kind: a serialized
     executable is only loadable into the exact runtime that produced it."""
     import jax
+    import jaxlib
 
     try:
         devs = jax.devices()
@@ -96,11 +137,8 @@ def backend_fingerprint() -> str:
         platform = devs[0].platform if devs else jax.default_backend()
     except Exception:  # uninitialized backend: still a stable string
         kind, platform = "none", "unknown"
-    jaxlib_version = getattr(
-        getattr(jax, "_src", None), "lib", None
-    )
-    jl = getattr(jaxlib_version, "version_str", None) or jax.__version__
-    return f"jax={jax.__version__};jaxlib={jl};backend={platform};kind={kind}"
+    return (f"jax={jax.__version__};jaxlib={jaxlib.__version__};"
+            f"backend={platform};kind={kind}")
 
 
 def _slot_names(x: Any) -> tuple:
@@ -203,7 +241,7 @@ class CompileCache:
 
     @classmethod
     def from_env(cls) -> "CompileCache":
-        return cls(os.environ.get(ENV_CACHE_DIR) or None)
+        return cls(os.path.join(cache_root(), _STORE_SUBDIR))
 
     @property
     def enabled(self) -> bool:
@@ -315,6 +353,30 @@ class CompileCache:
 # ---------------------------------------------------------------------------
 
 
+_tls = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_event(event: str, **_: Any) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _tls.jax_cache_hits = getattr(_tls, "jax_cache_hits", 0) + 1
+
+
+def _jax_cache_hits() -> int:
+    """JAX persistent-cache hits on THIS thread so far (JAX records them
+    on the compiling thread)."""
+    global _listening
+    if not _listening:
+        with _listener_lock:
+            if not _listening:
+                import jax
+
+                jax.monitoring.register_event_listener(_on_jax_event)
+                _listening = True
+    return getattr(_tls, "jax_cache_hits", 0)
+
+
 def _phase(reg, phase: str, seconds: float) -> None:
     reg.increment("parser_compile_total", labels={"phase": phase})
     reg.increment("parser_compile_seconds_total", seconds,
@@ -326,7 +388,7 @@ class AotExecutor:
     per-shape AOT compilation and a persistent executable cache.
 
     Resolution order per (B, L) shape bucket: in-memory map (artifact
-    preloads land here) -> disk cache (``LOGPARSER_TPU_COMPILE_CACHE``)
+    preloads land here) -> disk cache (:func:`cache_root`)
     -> explicit lower + compile (then written back to disk).  Each phase is
     timed into ``parser_compile_seconds_total{phase=lower|compile|
     serialize|deserialize}``.
@@ -341,9 +403,15 @@ class AotExecutor:
         fingerprint: str,
         serializable: bool = True,
         cache: Optional[CompileCache] = None,
+        devices: Optional[Sequence[Any]] = None,
     ) -> None:
         self._jit = jit_fn
         self.fingerprint = fingerprint
+        # What a reloaded executable runs on: the mesh's devices, or None
+        # for the default device.  Left to JAX, deserialization loads onto
+        # EVERY local device and the first call then fails on a multi-chip
+        # host (args sharded for 1 device, executable for N).
+        self._devices = list(devices) if devices is not None else None
         # Mesh-sharded executors compile against THIS process's device
         # set; their serialized form is not portable, so they AOT-compile
         # in memory but skip the disk/artifact round-trip.
@@ -351,6 +419,11 @@ class AotExecutor:
         self._cache = cache
         self._execs: Dict[Tuple[int, int], Callable] = {}
         self._payloads: Dict[Tuple[int, int], bytes] = {}
+        # Shapes whose executable JAX's persistent cache answered: never
+        # re-serialized (an XLA:CPU executable loaded from that cache
+        # serializes without its function library, and the reload then
+        # faults on every call).  The next process gets the same hit.
+        self._from_jax_cache: set = set()
         self._lock = threading.Lock()
 
     # -- plumbing --------------------------------------------------------
@@ -450,7 +523,10 @@ class AotExecutor:
             avals = self._avals(b, l)
             in_tree = jtu.tree_structure((avals, {}))
             out_tree = jtu.tree_structure(jax.eval_shape(self._jit, *avals))
-            exe = se.deserialize_and_load(payload, in_tree, out_tree)
+            exe = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=self._devices or jax.devices()[:1],
+            )
         except Exception as exc:
             reg.increment("compile_cache_errors_total",
                           labels={"kind": "deserialize"})
@@ -471,10 +547,13 @@ class AotExecutor:
         lowered = self._jit.lower(*avals)
         t1 = time.perf_counter()
         _phase(reg, "lower", t1 - t0)
+        hits0 = _jax_cache_hits()
         compiled = lowered.compile()
         t2 = time.perf_counter()
         _phase(reg, "compile", t2 - t1)
-        if self.serializable:
+        if _jax_cache_hits() > hits0:
+            self._from_jax_cache.add((b, l))
+        elif self.serializable:
             # Serialize only when there is a cache to write back to —
             # serialization costs a noticeable fraction of the compile
             # itself, and artifact export (export_payloads) serializes
@@ -513,7 +592,8 @@ class AotExecutor:
         ``TpuBatchParser.to_bytes`` to embed them in the artifact)."""
         with self._lock:
             out = dict(self._payloads)
-            missing = [s for s in self._execs if s not in out]
+            missing = [s for s in self._execs
+                       if s not in out and s not in self._from_jax_cache]
         reg = _metrics()
         for (b, l) in missing:
             payload = self._serialize(self._execs[(b, l)], b, l, reg)

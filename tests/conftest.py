@@ -16,6 +16,20 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# Compile caches (JAX's persistent cache + the executable store, both
+# under JAX_COMPILATION_CACHE_DIR) go to a private directory per test
+# process, never the checkout's .jax_cache: runs stay hermetic and xdist
+# workers never share entries.  Tests that count compiles still point the
+# variable at their own directory.
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    import atexit
+    import shutil
+    import tempfile
+
+    _cache_dir = tempfile.mkdtemp(prefix="lptpu-test-cc-")
+    atexit.register(shutil.rmtree, _cache_dir, True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache_dir
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -267,7 +267,22 @@ def drill_lines():
 
 
 @pytest.fixture()
-def cache_env(tmp_path, monkeypatch):
+def no_jax_cache():
+    """JAX's persistent cache off: compiles here are real, so they land
+    in the executable store (an executable JAX's cache answered is never
+    re-serialized — see AotExecutor._from_jax_cache)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture()
+def cache_env(tmp_path, monkeypatch, no_jax_cache):
     from logparser_tpu.tpu.compile_cache import ENV_CACHE_DIR
 
     root = str(tmp_path / "cc")
@@ -319,13 +334,14 @@ print(json.dumps({
 
 
 @pytest.mark.slow
-def test_artifact_round_trip_cross_process(tmp_path, drill_lines, monkeypatch):
+def test_artifact_round_trip_cross_process(tmp_path, drill_lines, monkeypatch,
+                                           no_jax_cache):
     """The ship-to-worker contract: a fresh host loading a prewarmed
     artifact executes its first batch with ZERO lower/compile — asserted
     on the child's own counters, and the values must match the parent's."""
     from logparser_tpu.tpu.compile_cache import ENV_CACHE_DIR
 
-    monkeypatch.delenv(ENV_CACHE_DIR, raising=False)
+    monkeypatch.setenv(ENV_CACHE_DIR, str(tmp_path / "parent-cc"))
     from logparser_tpu.tpu.batch import TpuBatchParser
 
     parser = TpuBatchParser("combined", FIELDS)
@@ -338,7 +354,8 @@ def test_artifact_round_trip_cross_process(tmp_path, drill_lines, monkeypatch):
     with open(lines_json, "w") as f:
         json.dump(list(drill_lines), f)
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    env.pop(ENV_CACHE_DIR, None)  # no disk cache: the artifact must carry it
+    # An empty cache dir: the artifact must carry the executables.
+    env[ENV_CACHE_DIR] = str(tmp_path / "child-cc")
     out = subprocess.run(
         [sys.executable, "-c", _CHILD_CODE % (FIELDS,),
          artifact, lines_json],
@@ -354,15 +371,80 @@ def test_artifact_round_trip_cross_process(tmp_path, drill_lines, monkeypatch):
         assert got["values"][fid] == expected.to_pylist(fid), fid
 
 
+def test_executable_from_jax_cache_is_not_reserialized(
+    tmp_path, monkeypatch, drill_lines
+):
+    """When JAX's persistent cache answers a compile, the executable is
+    neither written to the store nor embedded in an artifact: an XLA:CPU
+    executable loaded from that cache re-serializes without its function
+    library, and every reload then faulted to the host oracle."""
+    import jax
+
+    from logparser_tpu.tpu.batch import TpuBatchParser
+    from logparser_tpu.tpu.compile_cache import ENV_CACHE_DIR
+
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    try:
+        reg = metrics()
+        first = TpuBatchParser("combined", FIELDS)
+        first.prewarm(batch_sizes=[64], max_line_len=256)  # fills JAX's
+        monkeypatch.setenv(ENV_CACHE_DIR, str(tmp_path / "store"))
+        writes0 = reg.get("compile_cache_writes_total")
+        again = TpuBatchParser("combined", FIELDS)
+        again.prewarm(batch_sizes=[64], max_line_len=256)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          floor)
+    assert reg.get("compile_cache_writes_total") == writes0
+    assert again._jitted._from_jax_cache
+    assert again._jitted.export_payloads() == {}
+    r = TpuBatchParser.from_bytes(again.to_bytes()).parse_batch(drill_lines)
+    assert r.oracle_rows == 0
+
+
+@pytest.mark.parametrize("source", ["artifact", "disk"])
+def test_reloaded_executable_runs_on_a_multi_device_host(
+    source, cache_env, drill_lines
+):
+    """A reloaded executable must run on the device it was compiled for.
+    Left to JAX, deserialization loads onto every local device, and on
+    the conftest's 8-device host the first call was refused and the whole
+    batch rerouted to the host oracle (exact, so only counters showed)."""
+    import jax
+
+    from logparser_tpu.tpu.batch import TpuBatchParser
+
+    assert len(jax.devices()) > 1
+    reg = metrics()
+    parser = TpuBatchParser("combined", FIELDS)
+    parser.prewarm(batch_sizes=[64], max_line_len=256)
+    expected = parser.parse_batch(drill_lines)
+    assert expected.oracle_rows == 0
+    watched = ("compile_cache_errors_total", "device_faults_total",
+               "device_fault_reroutes_total", "device_demotions_total")
+    before = {name: reg.total(name) for name in watched}
+    deser0 = reg.get("parser_compile_total", {"phase": "deserialize"})
+    if source == "artifact":
+        loaded = TpuBatchParser.from_bytes(parser.to_bytes())
+    else:
+        loaded = TpuBatchParser("combined", FIELDS)
+    got = loaded.parse_batch(drill_lines)
+    assert reg.get("parser_compile_total", {"phase": "deserialize"}) > deser0
+    assert got.oracle_rows == 0
+    assert got.to_dict() == expected.to_dict()
+    assert {name: reg.total(name) for name in watched} == before
+
+
 @pytest.mark.slow
 def test_artifact_fingerprint_drift_refused_with_identical_output(
-    tmp_path, drill_lines, monkeypatch
+    tmp_path, drill_lines, monkeypatch, no_jax_cache
 ):
     from logparser_tpu.tpu.batch import TpuBatchParser
     from logparser_tpu.tpu.compile_cache import ENV_CACHE_DIR
     import pickle
 
-    monkeypatch.delenv(ENV_CACHE_DIR, raising=False)
+    monkeypatch.setenv(ENV_CACHE_DIR, str(tmp_path / "cc"))
     parser = TpuBatchParser("combined", FIELDS)
     parser.prewarm(batch_sizes=[64], max_line_len=256)
     expected = parser.parse_batch(drill_lines)
@@ -457,15 +539,9 @@ def test_protocol_and_zone_device_native_on_combined():
         '"HEAD /c HTTP/2.0" 204 0 "-" "t/1.0"',
     ]
     reg = metrics()
-    routed0 = sum(
-        v for (n, lb), v in reg._counters.items()
-        if n == "oracle_routed_lines_total"
-    )
+    routed0 = reg.total("oracle_routed_lines_total")
     r = parser.parse_batch(lines)
-    routed1 = sum(
-        v for (n, lb), v in reg._counters.items()
-        if n == "oracle_routed_lines_total"
-    )
+    routed1 = reg.total("oracle_routed_lines_total")
     assert routed1 == routed0, "combined drill must stay fully on device"
     assert r.to_pylist(RESIDUAL_FIELDS[0]) == ["HTTP", "HTTP", "HTTP"]
     assert r.to_pylist(RESIDUAL_FIELDS[1]) == ["1.1", "1.0", "2.0"]

@@ -38,6 +38,70 @@ KEY = _ParserCache.key_of(CONFIG)
 
 
 # ---------------------------------------------------------------------------
+# one chip per spawned sidecar (logparser_tpu/chips.py; no chip needed:
+# the chip list is injected and no child is started)
+# ---------------------------------------------------------------------------
+
+
+class TestChipPinning:
+    @pytest.fixture()
+    def four_chips(self, monkeypatch):
+        from logparser_tpu import chips
+
+        monkeypatch.setattr(chips, "host_chips", lambda: [0, 1, 2, 3])
+
+    @pytest.mark.parametrize("n", [2, 3, 4])
+    def test_each_spawned_sidecar_gets_its_own_chip(self, four_chips, n):
+        front = FrontTier(n_sidecars=n)
+        try:
+            envs = [front.sidecar_env(i) for i in range(n)]
+        finally:
+            front.shutdown()
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == [
+            str(i) for i in range(n)]
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == n
+        for e in envs:
+            assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_lone_sidecar_keeps_the_hosts_chips(self, four_chips):
+        # One device-owning child fights nobody: it is not pinned, so a
+        # machine that lets this process open only some of its chips
+        # cannot be handed a chip index it cannot open.
+        front = FrontTier(n_sidecars=1)
+        front.shutdown()
+        assert front.sidecar_env(0) == {}
+
+    def test_more_sidecars_than_chips_refused_at_construction(
+            self, four_chips):
+        from logparser_tpu.chips import ChipOversubscribedError
+
+        with pytest.raises(ChipOversubscribedError, match="4 TPU chip"):
+            FrontTier(n_sidecars=5)
+
+    def test_injected_spawner_is_not_pinned(self, four_chips):
+        # Tests and the bench inject in-process sidecars: they own no
+        # child process, so no chip is handed out (and 5 > 4 is fine).
+        front = FrontTier(n_sidecars=5, spawner=lambda i: None)
+        front.shutdown()
+
+    @pytest.mark.parametrize("env, want", [
+        ({"JAX_PLATFORMS": "cpu"}, []),
+        ({"JAX_PLATFORMS": "tpu", "TPU_VISIBLE_CHIPS": "2,3"}, [2, 3]),
+    ])
+    def test_host_chips_follows_platform_and_own_pinning(
+            self, monkeypatch, env, want):
+        from logparser_tpu.chips import assign_chips, host_chips
+
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        assert host_chips() == want
+        envs = assign_chips(2)
+        assert [e.get("TPU_VISIBLE_CHIPS") for e in envs] == (
+            [str(c) for c in want] or [None] * 2)
+
+
+# ---------------------------------------------------------------------------
 # the pure supervision machine (fast tier: no sockets, no sleeps)
 # ---------------------------------------------------------------------------
 

@@ -415,6 +415,38 @@ def test_bad_pod_placement_rejected(tmp_path, corpus_file):
         run(job_spec(tmp_path, corpus_file, "bad", n_hosts=0))
 
 
+def test_local_hosts_pinned_one_chip_each(tmp_path, corpus_file,
+                                          monkeypatch):
+    """Subprocess hosts share the machine's chips: host i's environment
+    carries chip i, and more hosts than chips is refused before any host
+    starts (read from the launcher; no chip and no child needed)."""
+    from logparser_tpu import chips
+    from logparser_tpu.chips import ChipOversubscribedError
+    from logparser_tpu.pod import runner
+
+    monkeypatch.setattr(chips, "host_chips", lambda: [0, 1])
+    spec = PodSpec([corpus_file], "combined", FIELDS,
+                   str(tmp_path / "pod"), n_hosts=3)
+    launch_host = runner._launch_host
+    launched = []
+    monkeypatch.setattr(runner, "_launch_host",
+                        lambda *a, **k: launched.append(a))
+    with pytest.raises(ChipOversubscribedError):
+        run_pod(spec)
+    assert launched == []
+
+    seen = {}
+
+    class FakePopen:
+        def __init__(self, argv, **kw):
+            seen["argv"], seen["env"] = argv, kw["env"]
+
+    monkeypatch.setattr(runner.subprocess, "Popen", FakePopen)
+    launch_host(spec, 1, PodPolicy(), chip_env=chips.chip_env(1))
+    assert seen["env"]["TPU_VISIBLE_CHIPS"] == "1"
+    assert seen["argv"][seen["argv"].index("--host-index") + 1] == "1"
+
+
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------

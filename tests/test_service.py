@@ -1103,3 +1103,38 @@ def test_coalesce_wait_ms_invalid_is_config_error():
             assert b"coalesce_wait_ms" in body
         finally:
             sock.close()
+
+
+
+def test_sidecar_process_survives_successive_sessions():
+    """The sidecar CLI's first pyarrow import used to happen on a session
+    thread, and pyarrow 25's mimalloc pool then segfaulted the process on
+    its second session, once that thread had exited.  The service now
+    loads pyarrow on the constructing thread."""
+    import os
+    import subprocess
+    import sys
+
+    from logparser_tpu.tools.demolog import HEADLINE_FIELDS
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "logparser_tpu.service", "--sidecar"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        cwd=root, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    try:
+        line = proc.stdout.readline()
+        while line and not line.startswith("SIDECAR_READY "):
+            line = proc.stdout.readline()
+        port = json.loads(line.split(" ", 1)[1])["port"]
+        for seed in range(4):  # each session is a new, short-lived thread
+            client = ParseServiceClient("127.0.0.1", port, "combined",
+                                        HEADLINE_FIELDS, timeout=300)
+            table = client.parse(generate_combined_lines(256, seed=seed))
+            client.close()
+            assert table.num_rows == 256
+        assert proc.poll() is None
+    finally:
+        proc.kill()
+        proc.wait(10)
