@@ -17,18 +17,14 @@ from .observability import (  # noqa: F401
     CounterRegistry,
     Histogram,
     MetricsRegistry,
-    Tracer,
     counters,
     disable_stage_annotations,
-    disable_tracing,
     enable_stage_annotations,
-    enable_tracing,
     log_warning_once,
     metrics,
     observe_stage,
     pipeline_stage,
     suppressed_warning_counts,
-    tracer,
     version_banner,
 )
 from .core import (  # noqa: F401

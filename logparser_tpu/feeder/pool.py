@@ -67,9 +67,12 @@ Telemetry (the PR-2 metrics registry, docs/OBSERVABILITY.md):
 ``feeder_queue_depth`` gauge (producer-updated in threads mode, shared
 put-counters minus consumer gets in process mode — live on every
 platform, qsize-less or not); ``feeder_starvation_seconds_total`` (wall
-time the consumer spent blocked on an empty queue — the "is the chip
-starving" number); ring counters ``feeder_ring_slot_wait_seconds_total``
-(worker backpressure wait, shipped in descriptors),
+time the consumer spent blocked on an empty queue after the first
+batch — the "is the chip starving" number) and
+``feeder_fill_seconds_total`` (the first dequeue: worker start, first
+read and frame, first transfer); ring counters
+``feeder_ring_slot_wait_seconds_total`` (worker backpressure wait,
+shipped in descriptors),
 ``feeder_ring_bytes_inplace_total`` (bytes that crossed via the arena
 instead of a pipe) and ``feeder_ring_pickle_fallback_total`` (slot-
 overflow batches); recovery counters ``feeder_worker_restarts_total``,
@@ -79,7 +82,9 @@ overflow batches); recovery counters ``feeder_worker_restarts_total``,
 ``feeder_ring_descriptor_faults_total``,
 ``feeder_teardown_errors_total{site}``; per-shard/per-batch stage
 timings via ``observe_stage`` (``feeder_read``, ``feeder_encode``,
-``feeder_shard``).
+``feeder_shard``: other processes' seconds, carried in queue items); the
+consumer's own phases as ``pipeline_stage`` spans (``feeder_start``,
+``feeder_fill``, ``feeder_get``, ``feeder_detach``, ``feeder_close``).
 """
 from __future__ import annotations
 
@@ -90,7 +95,12 @@ import time
 import weakref
 from typing import Any, Dict, Iterator, List, Optional, Sequence
 
-from ..observability import log_warning_once, metrics, observe_stage
+from ..observability import (
+    log_warning_once,
+    metrics,
+    observe_stage,
+    pipeline_stage,
+)
 from .ring import RingFault
 from .shards import (
     DEFAULT_SHARD_BYTES,
@@ -1033,39 +1043,40 @@ class FeederPool:
         silently swallowed."""
         if self._closed:
             return
-        self._closed = True
-        _LIVE_POOLS.discard(self)
-        for stop in self._stops:
-            stop.set()
-        # Drain so workers blocked on a full queue observe the stop event
-        # promptly instead of at their next 0.1 s put timeout.  Pumped
-        # (process) lanes drain the pump buffer only — touching the mp
-        # queue directly risks the partial-frame recv_bytes hang.
-        for w, q in enumerate(self._queues):
-            pump = self._pumps[w] if self._pumps else None
-            if pump is not None:
-                pump.retire()
-                q = pump
-            try:
-                while True:
-                    q.get_nowait() if hasattr(q, "get_nowait") else q.get(
-                        timeout=0
-                    )
-            except _queue.Empty:
-                pass
-            except Exception as e:  # noqa: BLE001
-                note_teardown_error(LOG, "close.drain", e)
-        for p in self._procs:
-            self._join_escalate(p, self._shutdown_timeout_s)
-        for q in self._queues:
-            # mp.Queue feeder threads keep the process alive unless
-            # cancelled; plain queue.Queue has no such method.
-            if hasattr(q, "cancel_join_thread"):
-                q.cancel_join_thread()
-        for r in self._rings:
-            if r is not None:
-                r.close()
-        metrics().gauge_set("feeder_queue_depth", 0)
+        with pipeline_stage("feeder_close"):
+            self._closed = True
+            _LIVE_POOLS.discard(self)
+            for stop in self._stops:
+                stop.set()
+            # Drain so workers blocked on a full queue observe the stop event
+            # promptly instead of at their next 0.1 s put timeout.  Pumped
+            # (process) lanes drain the pump buffer only — touching the mp
+            # queue directly risks the partial-frame recv_bytes hang.
+            for w, q in enumerate(self._queues):
+                pump = self._pumps[w] if self._pumps else None
+                if pump is not None:
+                    pump.retire()
+                    q = pump
+                try:
+                    while True:
+                        q.get_nowait() if hasattr(q, "get_nowait") else q.get(
+                            timeout=0
+                        )
+                except _queue.Empty:
+                    pass
+                except Exception as e:  # noqa: BLE001
+                    note_teardown_error(LOG, "close.drain", e)
+            for p in self._procs:
+                self._join_escalate(p, self._shutdown_timeout_s)
+            for q in self._queues:
+                # mp.Queue feeder threads keep the process alive unless
+                # cancelled; plain queue.Queue has no such method.
+                if hasattr(q, "cancel_join_thread"):
+                    q.cancel_join_thread()
+            for r in self._rings:
+                if r is not None:
+                    r.close()
+            metrics().gauge_set("feeder_queue_depth", 0)
 
     def __enter__(self) -> "FeederPool":
         return self
@@ -1203,6 +1214,8 @@ class FeederPool:
             # their Empty windows (the transfer itself is throughput).
             self._primed = True
             self._stats["startup_s"] = time.perf_counter() - t_enter
+            metrics().increment("feeder_fill_seconds_total",
+                                self._stats["startup_s"])
         elif blocked > 0:
             self._stats["starvation_s"] += blocked
             metrics().increment("feeder_starvation_seconds_total", blocked)
@@ -1243,7 +1256,8 @@ class FeederPool:
         recovered behind this iterator (replay is deterministic, so the
         delivered byte stream is identical to an undisturbed run's);
         only an in-process quarantine failure raises FeederError."""
-        self._start()
+        with pipeline_stage("feeder_start"):
+            self._start()
         reg = metrics()
         sup = self.supervisor
         t_start = time.perf_counter()
@@ -1259,7 +1273,9 @@ class FeederPool:
                     self._pending_quarantine.discard(shard.index)
                 while not quarantined:
                     try:
-                        msg = self._get(self._lane(worker), worker)
+                        with pipeline_stage("feeder_get" if self._primed
+                                            else "feeder_fill"):
+                            msg = self._get(self._lane(worker), worker)
                     except WorkerFault as fault:
                         if self._handle_worker_fault(
                             worker, shard, delivered, fault
@@ -1415,7 +1431,11 @@ class FeederPool:
                             quarantined = True
                         continue
                     self._account_batch(eb)
-                    yield eb.detach() if detach else eb
+                    if detach:
+                        with pipeline_stage("feeder_detach",
+                                            items=eb.n_lines):
+                            eb = eb.detach()
+                    yield eb
                     delivered += 1
                 if quarantined:
                     reg.increment("feeder_shards_total")

@@ -49,7 +49,7 @@ from ..feeder.shards import (
     plan_shards,
     shards_for_host,
 )
-from ..observability import log_warning_once, metrics
+from ..observability import log_warning_once, metrics, pipeline_stage
 from .manifest import (
     MANIFEST_NAME,
     JobManifest,
@@ -295,149 +295,162 @@ def run_job(
     to keep jit compiles out of timed windows).  ``chaos`` arms fault
     injection (``ChaosSpec`` / grammar string; default: the
     ``LOGPARSER_TPU_CHAOS`` env var)."""
+    # Root span: every phase span below nests under it, so its self
+    # time is the part of the job that no phase covers.  Kept off the
+    # profiler track: a trace names each idle gap after the annotation
+    # that overlaps it most, and a root would take every name.
+    with pipeline_stage("job", annotate=False):
+        return _run_job(spec, resume, parser, chaos, policy)
+
+
+def _run_job(spec: JobSpec, resume: bool, parser: Any, chaos: Any,
+             policy: Optional[JobPolicy]) -> JobReport:
     policy = policy or JobPolicy()
     t_start = time.perf_counter()
     reg = metrics()
-    if spec.n_hosts < 1 or not 0 <= spec.host_index < spec.n_hosts:
-        raise ValueError(
-            f"bad pod placement: host {spec.host_index} of "
-            f"{spec.n_hosts}"
-        )
-    pod = spec.n_hosts > 1
-    own_name = (host_manifest_name(spec.host_index) if pod
-                else MANIFEST_NAME)
-    from ..analytics.spec import parse_aggregate_config
-
-    agg_spec = parse_aggregate_config(spec.aggregate)
-    sources_norm = normalize_sources(spec.sources)
-    plan = plan_shards(sources_norm, spec.shard_bytes)
-    out_dir = spec.out_dir
-    os.makedirs(out_dir, exist_ok=True)
-    fingerprint = spec.fingerprint(sources_norm)
-    manifest = JobManifest.load(out_dir, own_name)
-    if manifest is not None:
-        if not resume:
-            raise ManifestError(
-                f"{out_dir} already holds a job manifest; resume it "
-                "(the default) or clear the directory for a fresh run"
+    # The prologue, up to the stream: plan, manifest, sweep, committed
+    # set, parser and pool construction.
+    with pipeline_stage("job_plan"):
+        if spec.n_hosts < 1 or not 0 <= spec.host_index < spec.n_hosts:
+            raise ValueError(
+                f"bad pod placement: host {spec.host_index} of "
+                f"{spec.n_hosts}"
             )
-        mismatch = manifest.mismatch(fingerprint)
-        if mismatch:
-            raise ManifestError(
-                f"refusing to resume {out_dir}: manifest belongs to a "
-                f"different job ({mismatch})"
-            )
-    else:
-        manifest = JobManifest.fresh(fingerprint)
-        manifest.save(out_dir, own_name)
-    # Crash debris: tmp files can only be leftovers of an interrupted,
-    # uncommitted write — safe to sweep (committed files were renamed).
-    # Pod-safe: only debris whose writer pid is dead is swept — another
-    # live host's mid-write temp file must not be yanked out from under
-    # its fsync (sweepable_temp_files applies the dead-pid rule).
-    for name in sweepable_temp_files(out_dir):
-        try:
-            os.unlink(os.path.join(out_dir, name))
-            reg.increment("job_temp_files_swept_total")
-        except OSError as e:
-            log_warning_once(LOG, f"job: could not sweep {name}: {e}")
+        pod = spec.n_hosts > 1
+        own_name = (host_manifest_name(spec.host_index) if pod
+                    else MANIFEST_NAME)
+        from ..analytics.spec import parse_aggregate_config
 
-    # What to skip: every shard durably committed by ANYONE — this
-    # host's earlier runs, the merged top-level manifest, and (pod) the
-    # other hosts' manifests.  A fingerprint divergence in any commit
-    # log refuses the run, exactly like the single-manifest resume.
-    committed_before = set(committed_anywhere(
-        out_dir, fingerprint, preloaded={own_name: manifest}))
-    owned = (shards_for_host(plan, spec.n_hosts, spec.host_index)
-             if pod else plan)
-    remaining = [s for s in owned if s.index not in committed_before]
-    report = JobReport(out_dir=out_dir, shards_total=len(owned),
-                       skipped=len(owned) - len(remaining),
-                       n_hosts=spec.n_hosts, host_index=spec.host_index)
-    if report.skipped:
-        reg.increment("job_shards_skipped_total", report.skipped)
-    pool_chaos, writer_chaos, device_chaos = _split_chaos(chaos)
-    writer = JobWriter(out_dir, retries=policy.io_retries,
-                       backoff_base_s=policy.io_backoff_s,
-                       chaos=writer_chaos)
-    reg.increment("job_runs_total")
-    # One durable job = one connected trace (docs/OBSERVABILITY.md
-    # "Tracing"): a pod launcher hands its context down via the
-    # LOGPARSER_TPU_TRACEPARENT env; a standalone job head-samples
-    # under LOGPARSER_TPU_TRACE_SAMPLE.  Feeder shards and shard
-    # commits become child spans below.
-    from ..tracing import child_span, root_span
+        agg_spec = parse_aggregate_config(spec.aggregate)
+        sources_norm = normalize_sources(spec.sources)
+        plan = plan_shards(sources_norm, spec.shard_bytes)
+        out_dir = spec.out_dir
+        os.makedirs(out_dir, exist_ok=True)
+        fingerprint = spec.fingerprint(sources_norm)
+        manifest = JobManifest.load(out_dir, own_name)
+        if manifest is not None:
+            if not resume:
+                raise ManifestError(
+                    f"{out_dir} already holds a job manifest; resume it "
+                    "(the default) or clear the directory for a fresh run"
+                )
+            mismatch = manifest.mismatch(fingerprint)
+            if mismatch:
+                raise ManifestError(
+                    f"refusing to resume {out_dir}: manifest belongs to a "
+                    f"different job ({mismatch})"
+                )
+        else:
+            manifest = JobManifest.fresh(fingerprint)
+            manifest.save(out_dir, own_name)
+        # Crash debris: tmp files can only be leftovers of an interrupted,
+        # uncommitted write — safe to sweep (committed files were renamed).
+        # Pod-safe: only debris whose writer pid is dead is swept — another
+        # live host's mid-write temp file must not be yanked out from under
+        # its fsync (sweepable_temp_files applies the dead-pid rule).
+        for name in sweepable_temp_files(out_dir):
+            try:
+                os.unlink(os.path.join(out_dir, name))
+                reg.increment("job_temp_files_swept_total")
+            except OSError as e:
+                log_warning_once(LOG, f"job: could not sweep {name}: {e}")
 
-    job_span = root_span(
-        "job_run",
-        traceparent=os.environ.get("LOGPARSER_TPU_TRACEPARENT"),
-        attrs={"host_index": spec.host_index, "n_hosts": spec.n_hosts,
-               "shards": len(owned)},
-    )
-    job_ctx = job_span.context if job_span is not None else None
-    if not remaining:
-        report.wall_s = time.perf_counter() - t_start
-        if job_span is not None:
-            job_span.end(committed=0, skipped=report.skipped)
-        return report
+        # What to skip: every shard durably committed by ANYONE — this
+        # host's earlier runs, the merged top-level manifest, and (pod) the
+        # other hosts' manifests.  A fingerprint divergence in any commit
+        # log refuses the run, exactly like the single-manifest resume.
+        committed_before = set(committed_anywhere(
+            out_dir, fingerprint, preloaded={own_name: manifest}))
+        owned = (shards_for_host(plan, spec.n_hosts, spec.host_index)
+                 if pod else plan)
+        remaining = [s for s in owned if s.index not in committed_before]
+        report = JobReport(out_dir=out_dir, shards_total=len(owned),
+                           skipped=len(owned) - len(remaining),
+                           n_hosts=spec.n_hosts, host_index=spec.host_index)
+        if report.skipped:
+            reg.increment("job_shards_skipped_total", report.skipped)
+        pool_chaos, writer_chaos, device_chaos = _split_chaos(chaos)
+        writer = JobWriter(out_dir, retries=policy.io_retries,
+                           backoff_base_s=policy.io_backoff_s,
+                           chaos=writer_chaos)
+        reg.increment("job_runs_total")
+        # One durable job = one connected trace (docs/OBSERVABILITY.md
+        # "Tracing"): a pod launcher hands its context down via the
+        # LOGPARSER_TPU_TRACEPARENT env; a standalone job head-samples
+        # under LOGPARSER_TPU_TRACE_SAMPLE.  Feeder shards and shard
+        # commits become child spans below.
+        from ..tracing import child_span, root_span
 
-    own_parser = parser is None
-    # A caller-supplied parser joins the drill too (device faults belong
-    # to the parse step wherever the parser came from) but is handed
-    # back with its PRIOR arming restored in the finally below — a
-    # caller mid-drill of its own must not find its injections wiped.
-    armed_caller_parser = (not own_parser) and device_chaos is not None
-    prior_device_chaos = (
-        getattr(parser, "_device_chaos", None) if armed_caller_parser
-        else None
-    )
-    if own_parser:
-        from ..tpu.batch import TpuBatchParser
-
-        # Jobs deliver copy-mode IPC tables, never string_view columns:
-        # device view emission would be pure kernel + D2H waste here.
-        # data_parallel lays the fused parse over this host's local
-        # chips (jax.sharding mesh; docs/JOBS.md "Pod jobs").
-        parser = TpuBatchParser(
-            spec.log_format, list(spec.fields), view_fields=(),
-            data_parallel=spec.data_parallel,
-            device_chaos=device_chaos,
+        job_span = root_span(
+            "job_run",
+            traceparent=os.environ.get("LOGPARSER_TPU_TRACEPARENT"),
+            attrs={"host_index": spec.host_index, "n_hosts": spec.n_hosts,
+                   "shards": len(owned)},
         )
-        if chaos is not None and device_chaos is None:
-            # An EXPLICIT chaos arg with no device faults must override
-            # the constructor's env fallback — the caller already chose
-            # this run's whole fault plan.
-            parser.arm_device_chaos(None)
-    elif armed_caller_parser:
-        parser.arm_device_chaos(device_chaos)
-    if agg_spec is not None:
-        # Field-level spec validation needs the built parser; a bad
-        # spec must refuse the job BEFORE the pool spins up (and must
-        # not leak a just-built parser's worker pools).
-        try:
-            agg_spec.validate_for(parser)
-        except Exception:
-            if own_parser:
-                parser.close()
-            raise
+        job_ctx = job_span.context if job_span is not None else None
+        if not remaining:
+            report.wall_s = time.perf_counter() - t_start
+            if job_span is not None:
+                job_span.end(committed=0, skipped=report.skipped)
+            return report
 
-    # The pool runs over a RENUMBERED plan (FeederPool requires index ==
-    # position); remaining[pool_index] maps back to the global shard.
-    pool_shards = [replace(s, index=i) for i, s in enumerate(remaining)]
-    pool = FeederPool(
-        spec.sources,
-        workers=spec.workers or min(default_feeder_workers(),
-                                    max(1, len(pool_shards))),
-        shard_bytes=spec.shard_bytes,
-        batch_lines=spec.batch_lines,
-        transport=spec.transport,
-        use_processes=spec.use_processes,
-        chaos=pool_chaos,
-        # A batch job's full queue is its healthy steady state, not
-        # service overload — stay out of the admission signal.
-        backpressure_signal=False,
-        shard_plan=pool_shards,
-    )
+        own_parser = parser is None
+        # A caller-supplied parser joins the drill too (device faults belong
+        # to the parse step wherever the parser came from) but is handed
+        # back with its PRIOR arming restored in the finally below — a
+        # caller mid-drill of its own must not find its injections wiped.
+        armed_caller_parser = (not own_parser) and device_chaos is not None
+        prior_device_chaos = (
+            getattr(parser, "_device_chaos", None) if armed_caller_parser
+            else None
+        )
+        if own_parser:
+            from ..tpu.batch import TpuBatchParser
+
+            # Jobs deliver copy-mode IPC tables, never string_view columns:
+            # device view emission would be pure kernel + D2H waste here.
+            # data_parallel lays the fused parse over this host's local
+            # chips (jax.sharding mesh; docs/JOBS.md "Pod jobs").
+            parser = TpuBatchParser(
+                spec.log_format, list(spec.fields), view_fields=(),
+                data_parallel=spec.data_parallel,
+                device_chaos=device_chaos,
+            )
+            if chaos is not None and device_chaos is None:
+                # An EXPLICIT chaos arg with no device faults must override
+                # the constructor's env fallback — the caller already chose
+                # this run's whole fault plan.
+                parser.arm_device_chaos(None)
+        elif armed_caller_parser:
+            parser.arm_device_chaos(device_chaos)
+        if agg_spec is not None:
+            # Field-level spec validation needs the built parser; a bad
+            # spec must refuse the job BEFORE the pool spins up (and must
+            # not leak a just-built parser's worker pools).
+            try:
+                agg_spec.validate_for(parser)
+            except Exception:
+                if own_parser:
+                    parser.close()
+                raise
+
+        # The pool runs over a RENUMBERED plan (FeederPool requires index ==
+        # position); remaining[pool_index] maps back to the global shard.
+        pool_shards = [replace(s, index=i) for i, s in enumerate(remaining)]
+        pool = FeederPool(
+            spec.sources,
+            workers=spec.workers or min(default_feeder_workers(),
+                                        max(1, len(pool_shards))),
+            shard_bytes=spec.shard_bytes,
+            batch_lines=spec.batch_lines,
+            transport=spec.transport,
+            use_processes=spec.use_processes,
+            chaos=pool_chaos,
+            # A batch job's full queue is its healthy steady state, not
+            # service overload — stay out of the admission signal.
+            backpressure_signal=False,
+            shard_plan=pool_shards,
+        )
 
     meta: deque = deque()
     # One feeder_shard span per shard the fabric feeds: opened when the
@@ -541,7 +554,8 @@ def run_job(
         nonlocal current, acc, commits_this_run
         end = pool_idx if pool_idx is not None else len(pool_shards)
         while current is not None and current < end:
-            _commit(current, acc)
+            with pipeline_stage("job_commit"):
+                _commit(current, acc)
             acc = _ShardAccumulator()
             commits_this_run += 1
             if (policy.stop_after_shards is not None
@@ -582,12 +596,13 @@ def run_job(
             if pshard != current and not _advance_to(pshard):
                 report.stopped_early = True
                 return report
-            if agg_spec is not None:
-                _fold_outcome(remaining[pshard], bidx, src_bytes, result,
-                              acc)
-            else:
-                _fold_result(remaining[pshard], bidx, src_bytes, result,
-                             acc, reg)
+            with pipeline_stage("job_fold", items=n_lines):
+                if agg_spec is not None:
+                    _fold_outcome(remaining[pshard], bidx, src_bytes,
+                                  result, acc)
+                else:
+                    _fold_result(remaining[pshard], bidx, src_bytes,
+                                 result, acc, reg)
         if current is None and pool_shards:
             current = 0  # every shard was empty
         if not _advance_to(None):

@@ -30,7 +30,7 @@ import re
 import time
 from typing import Any, List, Optional, Tuple
 
-from ..observability import log_warning_once, metrics, observe_stage
+from ..observability import log_warning_once, metrics, pipeline_stage
 from .manifest import (
     JobManifest,
     ShardRecord,
@@ -185,33 +185,33 @@ class JobWriter:
         the record's ``rows`` field (there is no data table to count)."""
         from ..tpu.arrow_bridge import table_to_ipc_bytes
 
-        t0 = time.perf_counter()
         reg = metrics()
         data_file = data_hash = None
         reject_file = reject_hash = None
         agg_file = agg_hash = None
-        rows = 0
-        if data_table is not None and data_table.num_rows:
-            rows = int(data_table.num_rows)
-            data = table_to_ipc_bytes(data_table.combine_chunks())
-            data_file = DATA_FILE.format(index=shard.index)
-            data_hash = hashlib.blake2b(data).hexdigest()
-            self.write_file(data_file, data, shard.index)
-            reg.increment("job_bytes_written_total", len(data))
         if agg_state is not None:
             rows = int(agg_rows)
-            data = agg_state.to_ipc_bytes()
-            agg_file = AGG_FILE.format(index=shard.index)
-            agg_hash = hashlib.blake2b(data).hexdigest()
-            self.write_file(agg_file, data, shard.index)
-            reg.increment("job_bytes_written_total", len(data))
-        if reject_rows:
-            reject = table_to_ipc_bytes(build_reject_table(reject_rows))
-            reject_file = REJECT_FILE.format(index=shard.index)
-            reject_hash = hashlib.blake2b(reject).hexdigest()
-            self.write_file(reject_file, reject, shard.index)
-            reg.increment("job_bytes_written_total", len(reject))
-        observe_stage("job_write", time.perf_counter() - t0, items=rows)
+        else:
+            rows = int(data_table.num_rows) if data_table is not None else 0
+        with pipeline_stage("job_write", items=rows):
+            if data_table is not None and data_table.num_rows:
+                data = table_to_ipc_bytes(data_table.combine_chunks())
+                data_file = DATA_FILE.format(index=shard.index)
+                data_hash = hashlib.blake2b(data).hexdigest()
+                self.write_file(data_file, data, shard.index)
+                reg.increment("job_bytes_written_total", len(data))
+            if agg_state is not None:
+                data = agg_state.to_ipc_bytes()
+                agg_file = AGG_FILE.format(index=shard.index)
+                agg_hash = hashlib.blake2b(data).hexdigest()
+                self.write_file(agg_file, data, shard.index)
+                reg.increment("job_bytes_written_total", len(data))
+            if reject_rows:
+                reject = table_to_ipc_bytes(build_reject_table(reject_rows))
+                reject_file = REJECT_FILE.format(index=shard.index)
+                reject_hash = hashlib.blake2b(reject).hexdigest()
+                self.write_file(reject_file, reject, shard.index)
+                reg.increment("job_bytes_written_total", len(reject))
         reg.increment("job_rows_total", rows)
         return ShardRecord(
             shard=shard.index, source=shard.source,

@@ -27,12 +27,12 @@ New work:
   ``bench.py`` consumes the same :meth:`MetricsRegistry.stage_breakdown`
   definitions for its delivery report, so live serving and the bench speak
   identical stage names (docs/OBSERVABILITY.md is the inventory).
-- :class:`Tracer` — per-stage wall-time accounting for the batch pipeline
-  (encode, device submit, device fetch, column assembly, oracle fallback),
-  enabled via :func:`enable_tracing` or LOGPARSER_TPU_TRACE=1.  The stage set
-  mirrors the hot-path inventory in SURVEY §3.3.  The tracer additionally
-  makes the ``device`` stage block on kernel completion, so its numbers are
-  attribution-exact; the always-on registry never blocks the async dispatch.
+- :func:`pipeline_stage` — the one span primitive: inclusive time into the
+  ``stage_seconds`` histogram, self time (minus the stage's children on
+  the same thread) into ``stage_self_seconds_total``, and an optional
+  profiler annotation.  The stage set mirrors the hot-path inventory in
+  SURVEY §3.3 plus the batch job's phases.  Nothing here blocks on the
+  device: the ``device`` stage is dispatch time.
 - ``jax.profiler`` trace annotations: LOGPARSER_TPU_XPROF_STAGES=1 (or
   :func:`enable_stage_annotations`) wraps every :func:`pipeline_stage` span
   in a named ``jax.profiler.TraceAnnotation`` ("lp.<stage>"), so
@@ -50,7 +50,6 @@ import os
 import re
 import threading
 import time
-from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 LOG = logging.getLogger(__name__)
@@ -65,108 +64,10 @@ def _env_truthy(name: str) -> bool:
 # ---------------------------------------------------------------------------
 
 
-@dataclass
-class StageStats:
-    calls: int = 0
-    total_s: float = 0.0
-    last_s: float = 0.0
-    items: int = 0
-
-    def as_dict(self) -> Dict[str, Any]:
-        return {
-            "calls": self.calls,
-            "total_s": round(self.total_s, 6),
-            "last_s": round(self.last_s, 6),
-            "items": self.items,
-        }
-
-
-class Tracer:
-    """Per-stage wall-clock accounting.  Disabled tracers cost one attribute
-    check per stage; timing only happens when enabled."""
-
-    def __init__(self, enabled: bool = False):
-        self.enabled = enabled
-        self.stages: Dict[str, StageStats] = {}
-        # parse_batch runs on concurrent service threads; stats updates are
-        # read-modify-write and must not interleave.
-        self._lock = threading.Lock()
-
-    def _record(self, name: str, seconds: float, items: int) -> None:
-        with self._lock:
-            stats = self.stages.setdefault(name, StageStats())
-            stats.calls += 1
-            stats.total_s += seconds
-            stats.last_s = seconds
-            stats.items += items
-
-    @contextlib.contextmanager
-    def stage(self, name: str, items: int = 0) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._record(name, time.perf_counter() - t0, items)
-
-    def add(self, name: str, seconds: float, items: int = 0) -> None:
-        """Manual accounting for spans that don't nest as a with-block."""
-        if not self.enabled:
-            return
-        self._record(name, seconds, items)
-
-    def reset(self) -> None:
-        with self._lock:
-            self.stages.clear()
-
-    def report(self) -> Dict[str, Dict[str, Any]]:
-        with self._lock:
-            snapshot = {name: s.as_dict() for name, s in self.stages.items()}
-        return dict(sorted(snapshot.items()))
-
-    def pretty(self) -> str:
-        with self._lock:
-            stages = {
-                name: (s.calls, s.total_s, s.items)
-                for name, s in self.stages.items()
-            }
-        if not stages:
-            return "(no stages recorded)"
-        width = max(len(n) for n in stages)
-        lines = []
-        for name, (calls, total_s, items) in sorted(
-            stages.items(), key=lambda kv: -kv[1][1]
-        ):
-            rate = f"  {items / total_s:12.0f} items/s" if items and total_s else ""
-            lines.append(
-                f"{name:<{width}}  {calls:6d} calls  {total_s * 1000:10.2f} ms{rate}"
-            )
-        return "\n".join(lines)
-
-
-_GLOBAL_TRACER = Tracer(enabled=_env_truthy("LOGPARSER_TPU_TRACE"))
-
-
-def tracer() -> Tracer:
-    return _GLOBAL_TRACER
-
-
-def enable_tracing() -> Tracer:
-    _GLOBAL_TRACER.enabled = True
-    return _GLOBAL_TRACER
-
-
-def disable_tracing() -> Tracer:
-    _GLOBAL_TRACER.enabled = False
-    return _GLOBAL_TRACER
-
-
 @contextlib.contextmanager
 def profile(log_dir: str) -> Iterator[None]:
     """Capture a JAX profiler trace (xprof/tensorboard readable) around a
-    block — the device-side complement of the host Tracer."""
+    block — the device-side complement of the host stage spans."""
     import jax
 
     with jax.profiler.trace(log_dir):
@@ -566,8 +467,8 @@ def counter_sum(name: str) -> float:
 
 
 # ---------------------------------------------------------------------------
-# pipeline-stage instrumentation: registry (always) + tracer (when enabled)
-# + jax.profiler trace annotation (when enabled)
+# pipeline-stage instrumentation: registry (always) + jax.profiler trace
+# annotation (when enabled)
 # ---------------------------------------------------------------------------
 
 # Canonical hot-path stage names (docs/OBSERVABILITY.md): the batch pipeline
@@ -576,7 +477,8 @@ def counter_sum(name: str) -> float:
 PIPELINE_STAGES = (
     "encode",            # [B, L] uint8 packing (native framer / per-line)
     "h2d_stage",         # staged async upload enqueue (stream double-buffer)
-    "device",            # fused-executor dispatch (kernel time when tracing)
+    "device",            # fused-executor dispatch (async: enqueue time only)
+    "h2d_wait",          # block until the batch's staged inputs are on device
     "fetch",             # packed D2H of the device verdict rows
     "columns",           # packed rows -> typed numpy columns
     "csr_materialize",   # wildcard CSR segment table -> dicts/spans
@@ -584,6 +486,16 @@ PIPELINE_STAGES = (
     "assembly",          # BatchResult -> pyarrow Table (hostpool fan-out)
     "ipc",               # Arrow IPC stream serialization
     "aggregate",         # analytics pushdown: partial fetch + host fold
+    # The durable batch job (jobs/runner.py), from its root down:
+    "job",               # the whole run_job call (root; never annotated)
+    "job_plan",          # shard plan, manifest, sweep, parser + pool setup
+    "feeder_start",      # FeederPool rings + worker spawn
+    "feeder_fill",       # the pool's first dequeue (pipeline fill)
+    "feeder_get",        # every later dequeue
+    "feeder_detach",     # owned copy of a ring-slot batch
+    "job_fold",          # one result folded into its shard's accumulator
+    "job_commit",        # shard concat + write + manifest commit
+    "feeder_close",      # worker joins + ring arena unlinks
 )
 
 _ANNOTATE = {"enabled": _env_truthy("LOGPARSER_TPU_XPROF_STAGES")}
@@ -620,28 +532,51 @@ def set_stage_span_sink(
 
 
 def observe_stage(name: str, seconds: float, items: int = 0) -> None:
-    """Record one completed stage span: always into the metrics registry
-    (stage_seconds histogram + stage_items_total counter), and into the
-    global Tracer when tracing is enabled.  Batch granularity only."""
+    """Record one completed stage: the stage_seconds histogram and the
+    stage_items_total counter.  Batch granularity only.  For a region
+    that is not one contiguous block of this thread's time (another
+    process's seconds, a sum of pieces); a block is a
+    :func:`pipeline_stage`."""
     _GLOBAL_METRICS.observe("stage_seconds", seconds, labels={"stage": name})
     if items:
         _GLOBAL_METRICS.increment(
             "stage_items_total", items, labels={"stage": name}
         )
-    if _GLOBAL_TRACER.enabled:
-        _GLOBAL_TRACER._record(name, seconds, items)
     sink = _STAGE_SPAN_SINK
     if sink is not None:
         sink(name, seconds, items)
 
 
+# Per-thread stack of open pipeline stages: each entry accumulates the
+# inclusive seconds of its finished children, so a stage's self time is
+# its own duration minus theirs.
+_OPEN_STAGES = threading.local()
+
+
+def _stage_stack() -> List[List[float]]:
+    stack = getattr(_OPEN_STAGES, "stack", None)
+    if stack is None:
+        stack = _OPEN_STAGES.stack = []
+    return stack
+
+
 @contextlib.contextmanager
-def pipeline_stage(name: str, items: int = 0) -> Iterator[None]:
-    """Instrument one hot-path stage at batch granularity: one
-    perf_counter pair + one histogram update per batch (a few µs against
-    multi-ms batches), plus the optional profiler annotation."""
+def pipeline_stage(name: str, items: int = 0,
+                   annotate: bool = True) -> Iterator[None]:
+    """Instrument one stage at batch granularity: inclusive seconds into
+    ``stage_seconds{stage}``, self seconds (inclusive minus the stages
+    nested in it on this thread) into ``stage_self_seconds_total{stage}``,
+    plus the optional ``lp.<name>`` profiler annotation (``annotate=False``
+    keeps a root stage off the profiler's host track).  One perf_counter
+    pair, one push and one pop per stage per batch.
+
+    Rule: no stage may enclose a ``yield``.  A stage opened inside a
+    generator body then closes before the generator suspends, and nests
+    under whatever stage its consumer has open when it calls ``next()``;
+    a stage held open across a ``yield`` would swallow its consumer's
+    time and corrupt the stack."""
     ann = None
-    if _ANNOTATE["enabled"]:
+    if annotate and _ANNOTATE["enabled"]:
         try:
             import jax
 
@@ -649,13 +584,23 @@ def pipeline_stage(name: str, items: int = 0) -> Iterator[None]:
             ann.__enter__()
         except Exception:  # noqa: BLE001 — annotation is best-effort
             ann = None
+    stack = _stage_stack()
+    children = [0.0]
+    stack.append(children)
     t0 = time.perf_counter()
     try:
         yield
     finally:
+        seconds = time.perf_counter() - t0
+        stack.pop()
+        if stack:
+            stack[-1][0] += seconds
         if ann is not None:
             ann.__exit__(None, None, None)
-        observe_stage(name, time.perf_counter() - t0, items)
+        observe_stage(name, seconds, items)
+        _GLOBAL_METRICS.increment("stage_self_seconds_total",
+                                  seconds - children[0],
+                                  labels={"stage": name})
 
 
 def record_batch_shape(rows: int, padded_rows: int, line_len: int,

@@ -2109,7 +2109,7 @@ class TpuBatchParser:
         buffering: the upload overlaps whatever is already on device).
         Returns the enc tuple extended with the staged device arrays;
         a no-op for host-only parsers."""
-        from ..observability import metrics, observe_stage
+        from ..observability import metrics, pipeline_stage
 
         if self._executor_for(emit_views) is None:
             return enc
@@ -2120,21 +2120,22 @@ class TpuBatchParser:
             # would re-create exactly the allocation the clamp forbids.
             return enc
         self._check_device_budget(buf, lengths, B, emit_views)
-        t0 = time.perf_counter()
         try:
-            if self._mesh is not None:
-                # Per-device input sharding ON the H2D edge: each device
-                # receives only its batch slice, so the upload fans out
-                # across the mesh instead of landing whole on device 0 and
-                # resharding inside the jit (the dryrun_multichip feeder
-                # idiom promoted to the hot path).
-                from ..parallel.mesh import dp_shardings
+            with pipeline_stage("h2d_stage", items=B):
+                if self._mesh is not None:
+                    # Per-device input sharding ON the H2D edge: each
+                    # device receives only its batch slice, so the upload
+                    # fans out across the mesh instead of landing whole on
+                    # device 0 and resharding inside the jit (the
+                    # dryrun_multichip feeder idiom promoted to the hot
+                    # path).
+                    from ..parallel.mesh import dp_shardings
 
-                (buf_sh, len_sh), _ = dp_shardings(self._mesh)
-                staged = (jax.device_put(buf, buf_sh),
-                          jax.device_put(lengths, len_sh))
-            else:
-                staged = (jax.device_put(buf), jax.device_put(lengths))
+                    (buf_sh, len_sh), _ = dp_shardings(self._mesh)
+                    staged = (jax.device_put(buf, buf_sh),
+                              jax.device_put(lengths, len_sh))
+                else:
+                    staged = (jax.device_put(buf), jax.device_put(lengths))
         except Exception as e:  # noqa: BLE001 — staging is an optimization
             # A staging failure (device OOM mid-upload, lost device)
             # defers placement to dispatch time, where the fault layer
@@ -2154,7 +2155,6 @@ class TpuBatchParser:
             )
             _LOG.debug("staged H2D failed; deferring to dispatch: %s", e)
             return enc
-        observe_stage("h2d_stage", time.perf_counter() - t0, items=B)
         metrics().increment(
             "h2d_staged_bytes_total", int(buf.nbytes + lengths.nbytes)
         )
@@ -2331,19 +2331,21 @@ class TpuBatchParser:
         failure and never returns an approximate answer."""
         from ..analytics.device import accumulate_partials, fetch_partials
         from ..analytics.state import AggregateOutcome, AggregateState
-        from ..observability import metrics, observe_stage
+        from ..observability import metrics, pipeline_stage
 
         (lines, buf, lengths, overflow, B, padded_b, out, key,
          fault) = state
         agg = AggregateState(spec)
         fetched = None
         nbytes = 0
-        t0 = time.perf_counter()
         if out is not None and fault is None:
-            try:
-                fetched, nbytes = fetch_partials(out, spec, B, padded_b)
-            except Exception as e:  # noqa: BLE001 — classified below
-                fetched, fault = None, e
+            with pipeline_stage("aggregate", items=B):
+                try:
+                    fetched, nbytes = fetch_partials(out, spec, B, padded_b)
+                except Exception as e:  # noqa: BLE001 — classified below
+                    fetched, fault = None, e
+                if fetched is not None:
+                    accumulate_partials(agg, spec, fetched, buf)
         if fault is not None:
             from ..observability import log_warning_once
             from .device_faults import classify_device_error
@@ -2370,7 +2372,7 @@ class TpuBatchParser:
             # layer (bisect/reroute/breaker) judges its own faults.
             result = self._finish_batch(
                 (lines, buf, lengths, overflow, B, padded_b, None,
-                 self.csr_slots, False, None)
+                 self.csr_slots, False, None, None)
             )
             metrics().increment("analytics_batches_total",
                                 labels={"path": "fallback"})
@@ -2389,8 +2391,6 @@ class TpuBatchParser:
             )
         self._breaker.record_success()
         cls = fetched["cls"]
-        accumulate_partials(agg, spec, fetched, buf)
-        observe_stage("aggregate", time.perf_counter() - t0, items=B)
         metrics().increment("d2h_bytes_total", int(nbytes))
         metrics().increment("analytics_batches_total",
                             labels={"path": "device"})
@@ -2516,7 +2516,7 @@ class TpuBatchParser:
         return list(lines), buf, lengths, overflow, B, padded_b
 
     def _dispatch_batch(self, enc, emit_views: Optional[bool] = None):
-        from ..observability import metrics, pipeline_stage, tracer
+        from ..observability import metrics, pipeline_stage
 
         # enc may carry a 7th element: device arrays already staged by
         # _stage_h2d (the overlapped-upload path).
@@ -2551,12 +2551,6 @@ class TpuBatchParser:
                         out = fn(*staged)
                     else:
                         out = fn(jnp.asarray(buf), jnp.asarray(lengths))
-                    if tracer().enabled:
-                        # Dispatch is async: make the device stage contain
-                        # the actual kernel time instead of misattributing
-                        # it to the fetch stage (only when someone is
-                        # looking).
-                        out = jax.block_until_ready(out)
                 except Exception as e:  # noqa: BLE001 — absorbed at fetch
                     # Compile failures and allocation OOMs surface HERE
                     # (jit compiles synchronously at call); the fault
@@ -2564,7 +2558,7 @@ class TpuBatchParser:
                     # fault policy instead of raising out of the parse.
                     out, fault = None, e
         return (lines, buf, lengths, overflow, B, padded_b, out,
-                self.csr_slots, emit_views, fault)
+                self.csr_slots, emit_views, fault, staged)
 
     def _finish_batch(self, state) -> BatchResult:
         return self._materialize_packed(self._fetch_packed(state))
@@ -2587,7 +2581,7 @@ class TpuBatchParser:
         from ..observability import metrics, pipeline_stage
 
         (lines, buf, lengths, overflow, B, padded_b, out, out_slots,
-         emit_views, fault) = state
+         emit_views, fault, staged) = state
 
         from .pipeline import CSR_OVERFLOW_BIT
 
@@ -2596,6 +2590,15 @@ class TpuBatchParser:
             if fault is None:
                 try:
                     if out is not None and out_slots == self.csr_slots:
+                        if staged is not None:
+                            # The output cannot be ready before its
+                            # inputs are: waiting on the upload first
+                            # splits the one wait into H2D and the rest.
+                            with pipeline_stage("h2d_wait", items=B):
+                                self._run_guarded(
+                                    lambda: jax.block_until_ready(staged),
+                                    "h2d",
+                                )
                         # ONE packed [sum K_i, B] int32 output -> ONE
                         # device->host fetch (one transfer round-trip
                         # per batch).
@@ -2900,7 +2903,7 @@ class TpuBatchParser:
         return None
 
     def _materialize_packed(self, fetched) -> BatchResult:
-        from ..observability import metrics, observe_stage
+        from ..observability import metrics, observe_stage, pipeline_stage
 
         reg = metrics()
         (lines, buf, lengths, B, packed, valid, winner, plausible_any,
@@ -2916,236 +2919,232 @@ class TpuBatchParser:
             block = packed[u.row_offset : u.row_offset + u.layout.n_rows]
             return u.layout.get(block, fid, comp)[:B]
 
-        # Timestamps are taken unconditionally (perf_counter is ~20ns against
-        # a multi-ms batch) so a tracer enabled mid-batch still records real
-        # durations; trace.add() itself no-ops when disabled.
-        t_columns = time.perf_counter()
-        ts_cache: Dict[tuple, tuple] = {}
+        with pipeline_stage("columns", items=B):
+            ts_cache: Dict[tuple, tuple] = {}
 
-        def unit_ts(u: FormatUnit, ui: int, plan: _FieldPlan):
-            """Decoded timestamp component bundle, cached per (unit, token,
-            steps) so N requested outputs of one timestamp decode it once."""
-            from .pipeline import ts_group_key
+            def unit_ts(u: FormatUnit, ui: int, plan: _FieldPlan):
+                """Decoded timestamp component bundle, cached per (unit, token,
+                steps) so N requested outputs of one timestamp decode it once."""
+                from .pipeline import ts_group_key
 
-            key = (ui, ts_group_key(plan))
-            got = ts_cache.get(key)
-            if got is None:
-                block = packed[u.row_offset : u.row_offset + u.layout.n_rows]
-                comp, ok = u.layout.get_ts_components(block, plan)
-                # Third element: the derive() memo sharing epoch/UTC/ISO
-                # intermediates across this bundle's requested outputs.
-                got = ({k: v[:B] for k, v in comp.items()}, ok[:B], {})
-                ts_cache[key] = got
-            return got
+                key = (ui, ts_group_key(plan))
+                got = ts_cache.get(key)
+                if got is None:
+                    block = packed[u.row_offset : u.row_offset + u.layout.n_rows]
+                    comp, ok = u.layout.get_ts_components(block, plan)
+                    # Third element: the derive() memo sharing epoch/UTC/ISO
+                    # intermediates across this bundle's requested outputs.
+                    got = ({k: v[:B] for k, v in comp.items()}, ok[:B], {})
+                    ts_cache[key] = got
+                return got
 
-        for fid in self.requested:
-            merged = self.plan_by_id[fid]
-            group = self._plan_group(merged)
-            if packed is None or group in ("host", "wild"):
-                # host: oracle-only.  wild wildcards (.*) deliver
-                # exclusively through overrides; wild CONCRETE fields
-                # (query.img) get their span column filled directly by
-                # _materialize_csr — fresh arrays, it writes into them.
-                concrete_wild = (
-                    packed is not None
-                    and group == "wild"
-                    and merged.comp != "*"
-                    and not getattr(merged, "attr", "")
-                )
-                columns[fid] = {
-                    "kind": "span",
-                    "starts": np.zeros(B, dtype=np.int32),
-                    "ends": np.zeros(B, dtype=np.int32),
-                    "ok": np.zeros(B, dtype=bool),
-                    "null": np.zeros(B, dtype=bool) if concrete_wild
-                    else zeros_null,
-                }
-                continue
-            if group == "span":
-                col = {
-                    "kind": "span",
-                    "starts": np.zeros(B, dtype=np.int32),
-                    "ends": np.zeros(B, dtype=np.int32),
-                    "ok": np.zeros(B, dtype=bool),
-                    "null": np.zeros(B, dtype=bool),
-                    "amp": np.zeros(B, dtype=bool),
-                    "fix": np.zeros(B, dtype=bool),
-                    # Which per-row micro-materialization `fix` rows need:
-                    # the final uri chain step decides (path: %-repair +
-                    # percent-decode; query: %-repair only).
-                    "fix_mode": (
-                        merged.steps[-1][1]
-                        if merged.steps and merged.steps[-1][0] == "uri"
-                        else ""
-                    ),
-                }
-            elif group == "obj":
-                col = {
-                    "kind": "obj",
-                    "values": np.full(B, None, dtype=object),
-                    "ok": np.zeros(B, dtype=bool),
-                    "null": zeros_null,
-                }
-            else:
-                col = {
-                    "kind": "numeric",
-                    "values": np.zeros(B, dtype=np.int64),
-                    "null": np.zeros(B, dtype=bool),
-                    "null_zero": np.zeros(B, dtype=bool),
-                    "ok": np.zeros(B, dtype=bool),
-                }
-            for ui, u in enumerate(self.units):
-                plan = u.plan_for(fid)
-                if not self._unit_decodable(u, fid):
-                    continue  # lines won by this unit go through the oracle
-                sel = winner == ui
-                if not sel.any():
+            for fid in self.requested:
+                merged = self.plan_by_id[fid]
+                group = self._plan_group(merged)
+                if packed is None or group in ("host", "wild"):
+                    # host: oracle-only.  wild wildcards (.*) deliver
+                    # exclusively through overrides; wild CONCRETE fields
+                    # (query.img) get their span column filled directly by
+                    # _materialize_csr — fresh arrays, it writes into them.
+                    concrete_wild = (
+                        packed is not None
+                        and group == "wild"
+                        and merged.comp != "*"
+                        and not getattr(merged, "attr", "")
+                    )
+                    columns[fid] = {
+                        "kind": "span",
+                        "starts": np.zeros(B, dtype=np.int32),
+                        "ends": np.zeros(B, dtype=np.int32),
+                        "ok": np.zeros(B, dtype=bool),
+                        "null": np.zeros(B, dtype=bool) if concrete_wild
+                        else zeros_null,
+                    }
                     continue
                 if group == "span":
-                    starts_col = unit_get(u, fid, "start")
-                    col["starts"] = np.where(sel, starts_col, col["starts"])
-                    col["ends"] = np.where(
-                        sel, starts_col + unit_get(u, fid, "len"), col["ends"]
-                    )
-                    col["ok"] = np.where(
-                        sel, unit_get(u, fid, "ok") != 0, col["ok"]
-                    )
-                    col["null"] = np.where(
-                        sel, unit_get(u, fid, "null") != 0, col["null"]
-                    )
-                    col["amp"] = np.where(
-                        sel, unit_get(u, fid, "amp") != 0, col["amp"]
-                    )
-                    col["fix"] = np.where(
-                        sel, unit_get(u, fid, "fix") != 0, col["fix"]
-                    )
-                elif plan.kind == "ts":
-                    comp, ok, memo = unit_ts(u, ui, plan)
-                    values = timefields.derive(
-                        comp, plan.comp, memo,
-                        locale=getattr(plan.meta, "locale", None),
-                    )
-                    # A non-geo fill on a (possibly geo-shared, mixed-
-                    # format) obj column: the Arrow dict/typed fast paths
-                    # only see geo-written state and would null these
-                    # rows — disable them for this column, either order.
-                    col["mixed_fill"] = True
-                    col["values"] = np.where(sel, values, col["values"])
-                    col["ok"] = np.where(sel, ok, col["ok"])
-                elif plan.kind == "geo":
-                    from .pipeline import geo_group_key
+                    col = {
+                        "kind": "span",
+                        "starts": np.zeros(B, dtype=np.int32),
+                        "ends": np.zeros(B, dtype=np.int32),
+                        "ok": np.zeros(B, dtype=bool),
+                        "null": np.zeros(B, dtype=bool),
+                        "amp": np.zeros(B, dtype=bool),
+                        "fix": np.zeros(B, dtype=bool),
+                        # Which per-row micro-materialization `fix` rows need:
+                        # the final uri chain step decides (path: %-repair +
+                        # percent-decode; query: %-repair only).
+                        "fix_mode": (
+                            merged.steps[-1][1]
+                            if merged.steps and merged.steps[-1][0] == "uri"
+                            else ""
+                        ),
+                    }
+                elif group == "obj":
+                    col = {
+                        "kind": "obj",
+                        "values": np.full(B, None, dtype=object),
+                        "ok": np.zeros(B, dtype=bool),
+                        "null": zeros_null,
+                    }
+                else:
+                    col = {
+                        "kind": "numeric",
+                        "values": np.zeros(B, dtype=np.int64),
+                        "null": np.zeros(B, dtype=bool),
+                        "null_zero": np.zeros(B, dtype=bool),
+                        "ok": np.zeros(B, dtype=bool),
+                    }
+                for ui, u in enumerate(self.units):
+                    plan = u.plan_for(fid)
+                    if not self._unit_decodable(u, fid):
+                        continue  # lines won by this unit go through the oracle
+                    sel = winner == ui
+                    if not sel.any():
+                        continue
+                    if group == "span":
+                        starts_col = unit_get(u, fid, "start")
+                        col["starts"] = np.where(sel, starts_col, col["starts"])
+                        col["ends"] = np.where(
+                            sel, starts_col + unit_get(u, fid, "len"), col["ends"]
+                        )
+                        col["ok"] = np.where(
+                            sel, unit_get(u, fid, "ok") != 0, col["ok"]
+                        )
+                        col["null"] = np.where(
+                            sel, unit_get(u, fid, "null") != 0, col["null"]
+                        )
+                        col["amp"] = np.where(
+                            sel, unit_get(u, fid, "amp") != 0, col["amp"]
+                        )
+                        col["fix"] = np.where(
+                            sel, unit_get(u, fid, "fix") != 0, col["fix"]
+                        )
+                    elif plan.kind == "ts":
+                        comp, ok, memo = unit_ts(u, ui, plan)
+                        values = timefields.derive(
+                            comp, plan.comp, memo,
+                            locale=getattr(plan.meta, "locale", None),
+                        )
+                        # A non-geo fill on a (possibly geo-shared, mixed-
+                        # format) obj column: the Arrow dict/typed fast paths
+                        # only see geo-written state and would null these
+                        # rows — disable them for this column, either order.
+                        col["mixed_fill"] = True
+                        col["values"] = np.where(sel, values, col["values"])
+                        col["ok"] = np.where(sel, ok, col["ok"])
+                    elif plan.kind == "geo":
+                        from .pipeline import geo_group_key
 
-                    _, column, table = plan.meta
-                    block = packed[u.row_offset : u.row_offset + u.layout.n_rows]
-                    key = geo_group_key(plan)
-                    rows_idx = u.layout.get(block, key, "row")[:B]
-                    ok = (u.layout.get(block, key, "ok") != 0)[:B]
-                    arr = table.arrays[column][rows_idx]
-                    if column in table.vocabs:
-                        vocab = table.vocab_arrays[column]
-                        values = vocab[arr]
-                        # Keep the vocab CODES for the Arrow bridge: geo
-                        # strings are low-cardinality, so the column can
-                        # build as dictionary.take(codes) with zero
-                        # per-row inference.  A second fill from a
-                        # DIFFERENT vocab (mixed-format batch over
-                        # distinct .mmdb tables) disables the fast path.
-                        if "dict_codes" not in col:
-                            col["dict_codes"] = np.full(B, -1, dtype=np.int64)
-                            col["dict_values"] = vocab
-                        if col.get("dict_values") is vocab:
-                            col["dict_codes"] = np.where(
-                                sel, arr.astype(np.int64), col["dict_codes"]
-                            )
+                        _, column, table = plan.meta
+                        block = packed[u.row_offset : u.row_offset + u.layout.n_rows]
+                        key = geo_group_key(plan)
+                        rows_idx = u.layout.get(block, key, "row")[:B]
+                        ok = (u.layout.get(block, key, "ok") != 0)[:B]
+                        arr = table.arrays[column][rows_idx]
+                        if column in table.vocabs:
+                            vocab = table.vocab_arrays[column]
+                            values = vocab[arr]
+                            # Keep the vocab CODES for the Arrow bridge: geo
+                            # strings are low-cardinality, so the column can
+                            # build as dictionary.take(codes) with zero
+                            # per-row inference.  A second fill from a
+                            # DIFFERENT vocab (mixed-format batch over
+                            # distinct .mmdb tables) disables the fast path.
+                            if "dict_codes" not in col:
+                                col["dict_codes"] = np.full(B, -1, dtype=np.int64)
+                                col["dict_values"] = vocab
+                            if col.get("dict_values") is vocab:
+                                col["dict_codes"] = np.where(
+                                    sel, arr.astype(np.int64), col["dict_codes"]
+                                )
+                            else:
+                                col["dict_values"] = None
+                        elif arr.dtype.kind == "f":
+                            values = arr.astype(object)
+                            values[np.isnan(arr)] = None
+                            self._geo_typed_fill(col, sel, arr.astype(np.float64),
+                                                 np.isnan(arr), "f")
                         else:
-                            col["dict_values"] = None
-                    elif arr.dtype.kind == "f":
-                        values = arr.astype(object)
-                        values[np.isnan(arr)] = None
-                        self._geo_typed_fill(col, sel, arr.astype(np.float64),
-                                             np.isnan(arr), "f")
-                    else:
-                        values = arr.astype(object)
-                        values[arr < 0] = None
-                        self._geo_typed_fill(col, sel, arr.astype(np.int64),
-                                             arr < 0, "i")
-                    col["values"] = np.where(sel, values, col["values"])
-                    col["ok"] = np.where(sel, ok, col["ok"])
-                elif plan.kind == "muid":
-                    from .pipeline import muid_group_key
+                            values = arr.astype(object)
+                            values[arr < 0] = None
+                            self._geo_typed_fill(col, sel, arr.astype(np.int64),
+                                                 arr < 0, "i")
+                        col["values"] = np.where(sel, values, col["values"])
+                        col["ok"] = np.where(sel, ok, col["ok"])
+                    elif plan.kind == "muid":
+                        from .pipeline import muid_group_key
 
-                    key = muid_group_key(plan)
-                    ok = unit_get(u, key, "ok") != 0
-                    col["mixed_fill"] = True  # see the ts branch
-                    if plan.comp == "ip":
-                        u32 = (
-                            unit_get(u, key, "ip").astype(np.int64)
-                            & 0xFFFFFFFF
+                        key = muid_group_key(plan)
+                        ok = unit_get(u, key, "ok") != 0
+                        col["mixed_fill"] = True  # see the ts branch
+                        if plan.comp == "ip":
+                            u32 = (
+                                unit_get(u, key, "ip").astype(np.int64)
+                                & 0xFFFFFFFF
+                            )
+                            # Vectorized dotted-quad: a 256-entry octet-string
+                            # vocab + object-array concatenation (no per-row
+                            # Python loop).
+                            octs = _OCTET_STRINGS
+                            dot = np.full(B, ".", dtype=object)
+                            vals = (
+                                octs[(u32 >> 24) & 255] + dot
+                                + octs[(u32 >> 16) & 255] + dot
+                                + octs[(u32 >> 8) & 255] + dot
+                                + octs[u32 & 255]
+                            )
+                            values = np.where(ok, vals, None)
+                            col["values"] = np.where(sel, values, col["values"])
+                        else:
+                            comp_row = {
+                                "epoch": "time", "processid": "pid",
+                                "counter": "counter", "threadindex": "thread",
+                            }[plan.comp]
+                            values = (
+                                unit_get(u, key, comp_row).astype(np.int64)
+                                & 0xFFFFFFFF
+                            )
+                            if plan.comp == "epoch":
+                                values = values * 1000
+                            col["values"] = np.where(sel, values, col["values"])
+                        col["ok"] = np.where(sel, ok, col["ok"])
+                    else:  # long / secmillis
+                        is_null = unit_get(u, fid, "null") != 0
+                        big = unit_get(u, fid, "big") != 0
+                        hi_row = unit_get(u, fid, "hi")
+                        values, ovf, wide = postproc.combine_long_limbs(
+                            hi_row,
+                            unit_get(u, fid, "lo"),
+                            unit_get(u, fid, "d18"),
+                            unit_get(u, fid, "lo_digits"),
+                            is_null,
                         )
-                        # Vectorized dotted-quad: a 256-entry octet-string
-                        # vocab + object-array concatenation (no per-row
-                        # Python loop).
-                        octs = _OCTET_STRINGS
-                        dot = np.full(B, ".", dtype=object)
-                        vals = (
-                            octs[(u32 >> 24) & 255] + dot
-                            + octs[(u32 >> 16) & 255] + dot
-                            + octs[(u32 >> 8) & 255] + dot
-                            + octs[u32 & 255]
-                        )
-                        values = np.where(ok, vals, None)
+                        # Overflow class (reference FORMAT_NUMBER has no width
+                        # bound): 19-digit values beyond Long.MAX (exact in
+                        # the uint64 frame) and >19-digit runs (hi row carries
+                        # the span for a host byte-patch).  Both deliver via
+                        # the post-loop patch, not the int64 column; is_null
+                        # never overlaps (a dash is 1 byte).
+                        ovf = ovf & ~big & ~is_null
+                        row_ok = unit_get(u, fid, "ok") != 0
+                        of_sel = sel & row_ok & valid & (ovf | big)
+                        if of_sel.any():
+                            overflow_patches.append((
+                                fid, plan, of_sel & big, of_sel & ovf,
+                                wide, hi_row,
+                            ))
+                        if plan.kind == "secmillis":
+                            values = values * 1000 + unit_get(u, fid, "milli")
+                        if plan.scale != 1:
+                            values = values * plan.scale
+                        if plan.null_mode == "zero_null":
+                            is_null = is_null | (values == 0)
                         col["values"] = np.where(sel, values, col["values"])
-                    else:
-                        comp_row = {
-                            "epoch": "time", "processid": "pid",
-                            "counter": "counter", "threadindex": "thread",
-                        }[plan.comp]
-                        values = (
-                            unit_get(u, key, comp_row).astype(np.int64)
-                            & 0xFFFFFFFF
-                        )
-                        if plan.comp == "epoch":
-                            values = values * 1000
-                        col["values"] = np.where(sel, values, col["values"])
-                    col["ok"] = np.where(sel, ok, col["ok"])
-                else:  # long / secmillis
-                    is_null = unit_get(u, fid, "null") != 0
-                    big = unit_get(u, fid, "big") != 0
-                    hi_row = unit_get(u, fid, "hi")
-                    values, ovf, wide = postproc.combine_long_limbs(
-                        hi_row,
-                        unit_get(u, fid, "lo"),
-                        unit_get(u, fid, "d18"),
-                        unit_get(u, fid, "lo_digits"),
-                        is_null,
-                    )
-                    # Overflow class (reference FORMAT_NUMBER has no width
-                    # bound): 19-digit values beyond Long.MAX (exact in
-                    # the uint64 frame) and >19-digit runs (hi row carries
-                    # the span for a host byte-patch).  Both deliver via
-                    # the post-loop patch, not the int64 column; is_null
-                    # never overlaps (a dash is 1 byte).
-                    ovf = ovf & ~big & ~is_null
-                    row_ok = unit_get(u, fid, "ok") != 0
-                    of_sel = sel & row_ok & valid & (ovf | big)
-                    if of_sel.any():
-                        overflow_patches.append((
-                            fid, plan, of_sel & big, of_sel & ovf,
-                            wide, hi_row,
-                        ))
-                    if plan.kind == "secmillis":
-                        values = values * 1000 + unit_get(u, fid, "milli")
-                    if plan.scale != 1:
-                        values = values * plan.scale
-                    if plan.null_mode == "zero_null":
-                        is_null = is_null | (values == 0)
-                    col["values"] = np.where(sel, values, col["values"])
-                    col["null"] = np.where(sel, is_null, col["null"])
-                    col["ok"] = np.where(sel, row_ok, col["ok"])
-                    if plan.null_mode == "dash_zero":
-                        col["null_zero"] = np.where(sel, True, col["null_zero"])
-            columns[fid] = col
-        observe_stage("columns", time.perf_counter() - t_columns, items=B)
+                        col["null"] = np.where(sel, is_null, col["null"])
+                        col["ok"] = np.where(sel, row_ok, col["ok"])
+                        if plan.null_mode == "dash_zero":
+                            col["null_zero"] = np.where(sel, True, col["null_zero"])
+                columns[fid] = col
 
         # Host fallback: invalid lines entirely; host-only fields for every line.
         # Numeric coercion follows the kind of the format that won the
@@ -3252,21 +3251,20 @@ class TpuBatchParser:
         # values from the packed segment table; a resilientUrlDecode failure
         # is exactly a line the host engine fails, so those rows drop to
         # invalid and take the oracle (which rejects them identically).
-        t_csr = time.perf_counter()
-        csr_failed = self._materialize_csr(
-            packed, winner, valid, overrides, columns, buf, B
-        )
-        extra_rows: List[int] = []
-        for i in csr_failed:
-            valid[i] = False
-            winner[i] = -1
-            for fid in self.requested:
-                overrides[fid].pop(i, None)
-            invalid_rows.add(i)
-            if i not in need_oracle:
-                need_oracle.add(i)
-                extra_rows.append(i)
-        observe_stage("csr_materialize", time.perf_counter() - t_csr, items=B)
+        with pipeline_stage("csr_materialize", items=B):
+            csr_failed = self._materialize_csr(
+                packed, winner, valid, overrides, columns, buf, B
+            )
+            extra_rows: List[int] = []
+            for i in csr_failed:
+                valid[i] = False
+                winner[i] = -1
+                for fid in self.requested:
+                    overrides[fid].pop(i, None)
+                invalid_rows.add(i)
+                if i not in need_oracle:
+                    need_oracle.add(i)
+                    extra_rows.append(i)
         # Escaped-quote decode accounting (round 18): lines the device
         # claimed THROUGH the escape-parity mask — the winning unit's
         # ESC_QUOTE_BIT on rows that survived every demotion above.
@@ -3451,7 +3449,9 @@ class TpuBatchParser:
         # oracle_fallback measures the wall time rescue ADDED to the batch:
         # submit/framing cost plus the blocked wait + delivery — parse
         # time hidden under the CSR stage by the pool thread is excluded
-        # (that overlap is the point of the batched rescue).
+        # (that overlap is the point of the batched rescue).  A sum of
+        # two pieces, not one contiguous block, so an observe_stage and
+        # not a pipeline_stage span.
         rescue_wall += time.perf_counter() - t_oracle
         observe_stage("oracle_fallback", rescue_wall, items=len(need_oracle))
         if oracle_rescued:

@@ -643,3 +643,60 @@ def test_cli_sigterm_maps_to_preempted_exit_code(
     assert main(argv) == 0
     rep2 = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rep2["complete"] and rep2["skipped"] == rep["committed"]
+
+
+# ---------------------------------------------------------------------------
+# job-phase spans: every second of run_job on its thread lies under a span
+# ---------------------------------------------------------------------------
+
+# The spans that nest directly under the ``job`` root when a row job runs
+# over feeder-framed batches (``encode`` is only the batch's adoption;
+# assembly nests under job_fold, job_write under job_commit).
+JOB_CHILD_STAGES = (
+    "job_plan", "feeder_start", "feeder_fill", "feeder_get",
+    "feeder_detach", "encode", "h2d_stage", "device", "h2d_wait", "fetch",
+    "columns", "csr_materialize", "job_fold", "job_commit", "feeder_close",
+)
+JOB_PHASE_STAGES = (
+    "job_plan", "feeder_start", "feeder_fill", "feeder_get",
+    "feeder_detach", "h2d_wait", "job_fold", "job_commit", "feeder_close",
+)
+
+
+def test_run_job_phases_account_for_the_whole_job(tmp_path):
+    reg = metrics()
+    sources = []
+    for i in range(2):
+        _, blob = make_corpus(n=120)
+        p = tmp_path / f"access.{i}.log"
+        p.write_bytes(blob)
+        sources.append(str(p))
+    spec = JobSpec(sources, FMT, FIELDS, str(tmp_path / "out"),
+                   shard_bytes=700, batch_lines=16, use_processes=False)
+
+    def snap():
+        return {s: (reg.histogram("stage_seconds", labels={"stage": s}).sum,
+                    reg.histogram("stage_seconds",
+                                  labels={"stage": s}).count,
+                    reg.get("stage_self_seconds_total",
+                            labels={"stage": s}))
+                for s in ("job",) + JOB_CHILD_STAGES}
+
+    before = snap()
+    fill0 = reg.get("feeder_fill_seconds_total")
+    report = run(spec)
+    after = snap()
+    assert report.complete and report.committed > 2
+
+    def delta(stage, k):
+        return after[stage][k] - before[stage][k]
+
+    assert delta("job", 1) == 1
+    for stage in JOB_PHASE_STAGES:
+        assert delta(stage, 1) > 0, stage
+    assert delta("feeder_fill", 1) == 1
+    assert delta("feeder_close", 1) == 1
+    assert reg.get("feeder_fill_seconds_total") > fill0
+    job_s = delta("job", 0)
+    children_s = sum(delta(s, 0) for s in JOB_CHILD_STAGES)
+    assert abs(delta("job", 2) + children_s - job_s) <= 0.01 * job_s

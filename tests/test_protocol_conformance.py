@@ -108,8 +108,8 @@ def test_01_column_types(service):
 def test_01_bytes_identical_with_telemetry(service):
     """Round-7 compatibility rule: a v1 session (no `stats` CONFIG key)
     replays the golden vector BYTE-identically whether or not telemetry
-    is active in the process (tracing enabled, registry populated, a
-    concurrent stats-enabled session having run)."""
+    is active in the process (registry populated, a concurrent
+    stats-enabled session having run)."""
     import json as _json
     import struct as _struct
 
@@ -125,35 +125,35 @@ def test_01_bytes_identical_with_telemetry(service):
         return frames
 
     baseline = replay()
-    # Turn telemetry loud: tracer on, registry churned by a stats session
-    # against the SAME server (exercises the stats-enabled code path).
-    tracer = logparser_tpu.enable_tracing()
+    # Churn the registry with a stats session against the SAME server
+    # (exercises the stats-enabled code path), then replay again.
+    stages_before = logparser_tpu.metrics().stage_breakdown()
+    sock = socket.create_connection((service.host, service.port))
     try:
-        sock = socket.create_connection((service.host, service.port))
-        try:
-            config = _json.dumps({
-                "log_format": "combined",
-                "fields": ["IP:connection.client.host"],
-                "stats": True,
-            }).encode()
-            sock.sendall(_struct.pack(">I", len(config)) + config)
-            line = (b'9.8.7.6 - - [01/Jan/2026:00:00:00 +0000] '
-                    b'"GET / HTTP/1.1" 200 5 "-" "x"')
-            payload = _struct.pack(">I", 1) + line
-            sock.sendall(_struct.pack(">I", len(payload)) + payload)
-            kind, _arrow = recv_response(sock)
-            assert kind == "arrow"
-            kind2, stats_frame = recv_response(sock)
-            assert kind2 == "arrow"  # a STATS frame is an ordinary frame
-            assert _json.loads(stats_frame)["v"] == 1
-            sock.sendall(_struct.pack(">I", 0))
-        finally:
-            sock.close()
-        with_telemetry = replay()
+        config = _json.dumps({
+            "log_format": "combined",
+            "fields": ["IP:connection.client.host"],
+            "stats": True,
+        }).encode()
+        sock.sendall(_struct.pack(">I", len(config)) + config)
+        line = (b'9.8.7.6 - - [01/Jan/2026:00:00:00 +0000] '
+                b'"GET / HTTP/1.1" 200 5 "-" "x"')
+        payload = _struct.pack(">I", 1) + line
+        sock.sendall(_struct.pack(">I", len(payload)) + payload)
+        kind, _arrow = recv_response(sock)
+        assert kind == "arrow"
+        kind2, stats_frame = recv_response(sock)
+        assert kind2 == "arrow"  # a STATS frame is an ordinary frame
+        assert _json.loads(stats_frame)["v"] == 1
+        sock.sendall(_struct.pack(">I", 0))
     finally:
-        logparser_tpu.disable_tracing()
+        sock.close()
+    with_telemetry = replay()
     assert with_telemetry == baseline
-    assert tracer.report()  # the replay really ran under tracing
+    # The replay really ran through the instrumented stages.
+    stages_after = logparser_tpu.metrics().stage_breakdown()
+    assert (stages_after["encode"]["calls"]
+            > stages_before.get("encode", {}).get("calls", 0))
 
 
 def test_02_bad_config_vector(service):

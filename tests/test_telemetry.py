@@ -130,19 +130,19 @@ def test_stage_breakdown_structure():
 
 
 def test_pipeline_stage_context_feeds_registry_and_tracer():
-    import logparser_tpu
-
-    before = metrics().stage_breakdown().get("encode", {}).get("calls", 0)
-    tr = logparser_tpu.enable_tracing()
-    tr.reset()
-    try:
-        with pipeline_stage("encode", items=7):
-            pass
-    finally:
-        logparser_tpu.disable_tracing()
-    after = metrics().stage_breakdown()["encode"]["calls"]
-    assert after == before + 1
-    assert tr.report()["encode"]["items"] == 7
+    """One span feeds the inclusive histogram, the items counter and the
+    self-time counter (the registry is the only stage recorder)."""
+    reg = metrics()
+    bd = reg.stage_breakdown().get("encode", {})
+    calls0, items0 = bd.get("calls", 0), bd.get("items", 0)
+    self0 = reg.get("stage_self_seconds_total", labels={"stage": "encode"})
+    with pipeline_stage("encode", items=7):
+        time.sleep(0.002)
+    after = reg.stage_breakdown()["encode"]
+    assert after["calls"] == calls0 + 1
+    assert after["items"] == items0 + 7
+    self1 = reg.get("stage_self_seconds_total", labels={"stage": "encode"})
+    assert self1 - self0 >= 0.002
 
 
 # ---------------------------------------------------------------------------
