@@ -14,7 +14,12 @@ in-memory blobs) into a steady, ORDERED stream of framed
   * ``"ring"`` (process default): each worker frames directly into a
     per-worker shared-memory slot arena (``feeder/ring.py``) and the
     queue carries only small slot descriptors — zero-copy bodies, with
-    slot exhaustion as the backpressure signal;
+    slot exhaustion as the backpressure signal.  Arenas are created
+    sparse (the workers fault a slot's pages in as they first frame into
+    it), and a frame larger than its slot goes to a shared-memory
+    segment of its own size that the worker creates under the slot's
+    token — only a segment that cannot be reserved (tmpfs full) ships
+    that batch pickled;
   * ``"pickle"`` (escape hatch ``LOGPARSER_TPU_FEEDER_PICKLE=1``, or
     the fallback when shared memory is unavailable): whole batches
     pickle through BOUNDED per-worker queues — a full queue blocks its
@@ -73,9 +78,11 @@ batch — the "is the chip starving" number) and
 read and frame, first transfer); ring counters
 ``feeder_ring_slot_wait_seconds_total`` (worker backpressure wait,
 shipped in descriptors),
-``feeder_ring_bytes_inplace_total`` (bytes that crossed via the arena
-instead of a pipe) and ``feeder_ring_pickle_fallback_total`` (slot-
-overflow batches); recovery counters ``feeder_worker_restarts_total``,
+``feeder_ring_bytes_inplace_total`` (bytes that crossed via shared
+memory instead of a pipe), ``feeder_segment_batches_total`` (batches
+mapped from a frame-sized segment) and
+``feeder_ring_pickle_fallback_total`` (batches that found no shared
+memory and crossed pickled); recovery counters ``feeder_worker_restarts_total``,
 ``feeder_shards_requeued_total``, ``feeder_shards_quarantined_total``,
 ``feeder_transport_demotions_total{from,to}``,
 ``feeder_ring_generation_mismatch_total``,
@@ -387,9 +394,9 @@ class FeederPool:
             else self.queue_batches + 2
         )
         # Default slot: room for batch_lines lines at a generous L plus
-        # the raw payload — a batch that still doesn't fit (pathological
-        # line bucket) ships pickled, so this is a fast path size, not a
-        # correctness bound.
+        # the raw payload — a batch that still doesn't fit (a long line
+        # bucket) is framed into a segment of its own size, so this is
+        # the recycled fast path's size, not a bound.
         self.slot_bytes = (
             int(slot_bytes) if slot_bytes
             else max(1 << 20, self.batch_lines * 768)
@@ -442,6 +449,7 @@ class FeederPool:
             "slot_wait_s": 0.0,
             "bytes_inplace": 0,
             "pickle_fallback_batches": 0,
+            "segment_batches": 0,
             "batches_reframed": 0,
         }
         self._depth_samples = 0
@@ -752,7 +760,6 @@ class FeederPool:
                     self.slot_bytes, self.ring_slots,
                     self._ctx.Queue() if as_process else _queue.Queue(),
                     name_hint=f"{os.getpid()}_{worker}r",
-                    prefault=False,  # mid-run rebuild: fault lazily
                 )
             except Exception as e:  # noqa: BLE001 — arena gone mid-run
                 log_warning_once(
@@ -1352,6 +1359,9 @@ class FeederPool:
                             inplace = ring.inplace_bytes(desc)
                             reg.increment("feeder_ring_bytes_inplace_total",
                                           inplace)
+                            if desc.segment:
+                                self._stats["segment_batches"] += 1
+                                reg.increment("feeder_segment_batches_total")
                             self._stats["slot_wait_s"] += desc.slot_wait_s
                             self._stats["bytes_inplace"] += inplace
                         if demote is not None:
@@ -1365,8 +1375,9 @@ class FeederPool:
                         worker_transport = (sup.transport_of[worker]
                                             if sup else self.transport)
                         if worker_transport == "ring":
-                            # Slot-overflow fallback batch (counted, not
-                            # fatal: the ring degrades per batch).  Its
+                            # Pickle fallback batch: no shared memory
+                            # could hold it (counted, not fatal: the
+                            # ring degrades per batch).  Its
                             # slot-acquire wait still happened — keep the
                             # backpressure signal honest under overflow.
                             self._stats["pickle_fallback_batches"] += 1

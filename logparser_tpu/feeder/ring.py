@@ -31,9 +31,23 @@ the int32 lengths view is aligned)::
     [align8(4*B) .. +B*L)     buf      uint8[B, L]
     [.. +payload_len)         payload  raw line bytes (with '\\n's)
 
-A batch whose framed size exceeds ``slot_bytes`` (a pathological line
-bucket) falls back to the pickled lane for that one batch — the ring
-degrades per batch, never wholesale.
+Arenas are created sparse: a slot's tmpfs pages are faulted in by the
+worker that first frames into it, in parallel with its framing, never
+by a serial zero pass on the consumer.
+
+A batch whose framed size exceeds ``slot_bytes`` (a large batch at a
+long line bucket) is framed into a SEGMENT of its own: the worker
+creates a shared-memory segment of exactly the frame's size, named
+after the slot it acquired (:func:`segment_name`), reserves its pages
+(``os.posix_fallocate``: a full tmpfs fails here with ENOSPC, never with
+SIGBUS mid-frame) and frames into it with the same layout at offset 0.
+The slot id stays the backpressure token — a worker never has more
+than ``n_slots`` frames outstanding, whichever memory backs them — and
+the generation ledger covers both.  The consumer maps the segment
+zero-copy; releasing the slot unlinks the segment's name, and
+:meth:`RingBatch.detach` keeps the mapping alive under owned views
+instead of copying the frame.  Only a failed reservation (ENOSPC)
+sends a batch over the pickled lane.
 
 Cleanup: the consumer process CREATES the arenas and the resource
 tracker holds their registrations, so a crashed consumer still gets
@@ -42,13 +56,21 @@ with the tracker too, but forkserver/spawn children SHARE the parent's
 tracker process, so the attach-side registration dedupes into the one
 the consumer already holds (no premature unlink, no double-unregister;
 see ``SlotWriter.__init__``) and the single unlink on pool close — or
-on consumer crash, via the tracker — clears it.  Orphaned workers
+on consumer crash, via the tracker — clears it.  Frame-sized segments
+are created (and registered) by the worker on that same shared tracker
+and unlinked by whoever ends their life: the consumer on release, the
+worker when it gives an unsent slot back, and :meth:`SlotRing.close`
+sweeps every slot's name (a worker that died between creating a segment
+and sending its descriptor).  Orphaned workers
 (consumer SIGKILLed) self-terminate via the parent-death watch in
 ``run_worker``, so nothing pins the tracker pipe open.  The module is
 jax-free and import-cheap (worker processes load it).
 """
 from __future__ import annotations
 
+import errno
+import mmap
+import os
 import secrets
 import time
 from dataclasses import dataclass, field
@@ -68,8 +90,69 @@ LOG = logging.getLogger(__name__)
 SLOT_ALIGN = 8
 
 #: /dev/shm segment name prefix — the leak checks in feeder_smoke and
-#: tests key on it.
+#: tests key on it (arenas and frame-sized segments alike).
 RING_NAME_PREFIX = "lpring"
+
+try:  # POSIX shared memory: what multiprocessing.shared_memory uses
+    import _posixshmem
+    from multiprocessing import resource_tracker
+except ImportError:  # pragma: no cover — no frame-sized segments here
+    _posixshmem = None
+
+
+def segment_name(ring_name: str, slot: int) -> str:
+    """The name of the frame-sized segment backing ``slot`` of arena
+    ``ring_name``.  One name per slot is enough: a segment lives only
+    while its slot's token is leased, and every path that returns the
+    token unlinks the name first."""
+    return f"{ring_name}s{slot}"
+
+
+def _create_segment(name: str, size: int) -> mmap.mmap:
+    """Create segment ``name`` of ``size`` bytes with its pages reserved
+    and map it.  Registered with the resource tracker (the consumer's,
+    shared by forkserver/spawn workers) like an arena, so a crashed
+    consumer's segments are unlinked too.  Raises ``OSError(ENOSPC)``
+    when tmpfs cannot hold it."""
+    path = "/" + name
+    fd = _posixshmem.shm_open(path, os.O_CREAT | os.O_EXCL | os.O_RDWR,
+                              mode=0o600)
+    try:
+        resource_tracker.register(path, "shared_memory")
+        try:
+            os.posix_fallocate(fd, 0, size)
+            return mmap.mmap(fd, size)
+        except BaseException:
+            unlink_segment(name)
+            raise
+    finally:
+        os.close(fd)
+
+
+def _attach_segment(name: str, size: int) -> mmap.mmap:
+    """Map an existing segment; the mapping alone keeps it alive (the
+    views over it own it, the file descriptor is closed here)."""
+    fd = _posixshmem.shm_open("/" + name, os.O_RDWR, mode=0o600)
+    try:
+        if os.fstat(fd).st_size < size:
+            raise ValueError(f"segment {name} is smaller than {size}B")
+        return mmap.mmap(fd, size)
+    finally:
+        os.close(fd)
+
+
+def unlink_segment(name: str) -> bool:
+    """Unlink segment ``name`` if it exists (and drop its tracker
+    registration).  Live mappings stay valid.  Returns whether it did."""
+    if _posixshmem is None:
+        return False
+    path = "/" + name
+    try:
+        _posixshmem.shm_unlink(path)
+    except FileNotFoundError:
+        return False
+    resource_tracker.unregister(path, "shared_memory")
+    return True
 
 
 def ring_available() -> bool:
@@ -105,7 +188,8 @@ def _shared_memory_cls():
 
 
 class SlotOverflow(Exception):
-    """The framed batch does not fit one slot (fall back to pickle)."""
+    """The framed batch has no shared memory to go to (fall back to
+    pickle): its frame-sized segment could not be reserved."""
 
 
 class RingFault(RuntimeError):
@@ -167,6 +251,9 @@ class SlotFrame:
     #: duplicate, or corruption intervened — verified in SlotRing.map,
     #: counted as feeder_ring_generation_mismatch_total by the pool.
     generation: int = 0
+    #: Name of the frame-sized segment holding this batch ("" = the
+    #: slot itself holds it).
+    segment: str = ""
 
 
 @dataclass
@@ -184,15 +271,17 @@ class RingSpec:
 @dataclass
 class RingBatch(EncodedBatch):
     """An EncodedBatch whose payload/buf/lengths are zero-copy views
-    into a ring slot.  The slot stays leased to this batch until
-    :meth:`release` — ``parse_batch_stream`` releases after the batch's
-    materialization (device upload done, rescue payload consumed);
-    :meth:`detach` converts to an owned plain batch and releases
-    immediately (the ``FeederPool.batches()`` default)."""
+    into a ring slot or its frame-sized segment.  The slot stays leased
+    to this batch until :meth:`release` — ``parse_batch_stream``
+    releases after the batch's materialization (device upload done,
+    rescue payload consumed); :meth:`detach` converts to an owned plain
+    batch and releases immediately (the ``FeederPool.batches()``
+    default)."""
 
     ring: Any = None            # consumer-side SlotRing
     slot: int = -1
     released: bool = False
+    segment: str = ""           # SlotFrame.segment
 
     def release(self) -> None:
         if self.ring is not None and not self.released:
@@ -200,12 +289,21 @@ class RingBatch(EncodedBatch):
             self.ring.release(self.slot)
 
     def detach(self) -> EncodedBatch:
+        if self.segment:
+            # The segment's mapping is this batch's alone (no slot
+            # recycles under it): the views become the owned arrays,
+            # and release() drops only the name and the token.
+            payload, buf, lengths = self.payload, self.buf, self.lengths
+        else:
+            payload = bytes(self.payload)
+            buf = np.array(self.buf, copy=True)
+            lengths = np.array(self.lengths, copy=True)
         eb = EncodedBatch(
             shard=self.shard,
             index=self.index,
-            payload=bytes(self.payload),
-            buf=np.array(self.buf, copy=True),
-            lengths=np.array(self.lengths, copy=True),
+            payload=payload,
+            buf=buf,
+            lengths=lengths,
             overflow=list(self.overflow),
             n_lines=self.n_lines,
             read_s=self.read_s,
@@ -230,6 +328,9 @@ class SlotWriter:
         # overflow/stop putbacks send nothing and must not advance the
         # generation the consumer's ledger expects).
         self._sent = [0] * spec.n_slots
+        # Per-slot name of the segment framed for it and not yet sent
+        # (the worker still owns it: putback unlinks it).
+        self._segment = [""] * spec.n_slots
         self._owns_attach = shm is None
         if shm is None:
             # Attaching registers with the resource tracker too (pre-3.13
@@ -256,7 +357,11 @@ class SlotWriter:
                 continue
 
     def putback(self, slot: int) -> None:
-        """Return an acquired-but-unused slot (overflow/stop paths)."""
+        """Return an acquired-but-unsent slot (overflow/stop paths),
+        unlinking the segment framed for it, if any."""
+        if self._segment[slot]:
+            unlink_segment(self._segment[slot])
+            self._segment[slot] = ""
         self.spec.free_q.put(slot)
 
     def next_generation(self, slot: int) -> int:
@@ -265,28 +370,48 @@ class SlotWriter:
         after the descriptor actually crossed the queue."""
         return self._sent[slot]
 
+    def segment_of(self, slot: int) -> str:
+        """The segment the last :meth:`frame` into ``slot`` used ("" =
+        the slot itself): the descriptor's ``segment``."""
+        return self._segment[slot]
+
     def note_sent(self, slot: int) -> None:
         self._sent[slot] += 1
+        self._segment[slot] = ""  # the consumer owns it now
+
+    def _reserve_segment(self, slot: int, size: int) -> mmap.mmap:
+        if _posixshmem is None:
+            raise SlotOverflow(f"batch needs {size}B > slot_bytes="
+                               f"{self.spec.slot_bytes}, no POSIX shm")
+        name = segment_name(self.spec.name, slot)
+        try:
+            mm = _create_segment(name, size)
+        except OSError as e:
+            if e.errno != errno.ENOSPC:
+                raise
+            raise SlotOverflow(f"no room for a {size}B segment: {e}") from e
+        self._segment[slot] = name
+        return mm
 
     def frame(self, chunk, line_len: int, slot: int):
         """Frame ``chunk`` (one batch's raw line bytes) directly into
-        ``slot``: encode_blob packs the [B, L] buffer and lengths into
-        slot-backed views, the payload is memcpy'd beside them.  Returns
-        ``(n_lines, L, overflow)``; raises :class:`SlotOverflow` when
-        the framed batch cannot fit the slot."""
+        ``slot`` — or, when the frame outgrows the slot, into a segment
+        of the frame's own size (:meth:`segment_of`): encode_blob packs
+        the [B, L] buffer and lengths into shared-memory views, the
+        payload is memcpy'd beside them.  Returns ``(n_lines, L,
+        overflow)``; raises :class:`SlotOverflow` when the segment
+        cannot be reserved."""
         from ..native import encode_blob
 
-        base = slot * self.spec.slot_bytes
-        mv = self.shm.buf
-        carved: List[int] = []
+        carved: List[Any] = []  # [memory, base offset, payload offset]
 
         def alloc(n: int, L: int):
             buf_off, payload_off, total = slot_layout(n, L, len(chunk))
-            if total > self.spec.slot_bytes:
-                raise SlotOverflow(
-                    f"batch needs {total}B > slot_bytes={self.spec.slot_bytes}"
-                )
-            carved[:] = [payload_off]
+            if total <= self.spec.slot_bytes:
+                mv, base = self.shm.buf, slot * self.spec.slot_bytes
+            else:
+                mv, base = self._reserve_segment(slot, total), 0
+            carved[:] = [mv, base, payload_off]
             lengths = np.frombuffer(mv, dtype=np.int32, count=n, offset=base)
             buf = np.frombuffer(
                 mv, dtype=np.uint8, count=n * L, offset=base + buf_off
@@ -296,15 +421,29 @@ class SlotWriter:
         buf, lengths, overflow = encode_blob(
             chunk, line_len=line_len, alloc=alloc
         )
-        (payload_off,) = carved
+        mv, base, payload_off = carved
         if len(chunk):
             dst = np.frombuffer(
                 mv, dtype=np.uint8, count=len(chunk), offset=base + payload_off
             )
             dst[:] = np.frombuffer(chunk, dtype=np.uint8)
-        return int(buf.shape[0]), int(buf.shape[1]), list(overflow)
+            del dst
+        n, L = buf.shape
+        del buf, lengths
+        if self._segment[slot]:
+            # The bytes live in the segment, not in this mapping: the
+            # consumer maps the segment by name.
+            try:
+                mv.close()
+            except BufferError:
+                pass  # a view still pins it: it goes with the view
+        return int(n), int(L), list(overflow)
 
     def close(self) -> None:
+        for slot, name in enumerate(self._segment):
+            if name:  # framed, never sent: nobody else will unlink it
+                unlink_segment(name)
+                self._segment[slot] = ""
         if self._owns_attach:
             try:
                 self.shm.close()
@@ -313,12 +452,13 @@ class SlotWriter:
 
 
 class SlotRing:
-    """Consumer-side owner of one worker's arena: creates the segment,
-    seeds the free queue, maps descriptors into :class:`RingBatch`
-    views, recycles released slots, and unlinks on close."""
+    """Consumer-side owner of one worker's arena: creates it (sparse),
+    seeds the free queue, maps descriptors — slot or frame-sized
+    segment — into :class:`RingBatch` views, recycles released slots,
+    and unlinks on close."""
 
     def __init__(self, slot_bytes: int, n_slots: int, free_q: Any,
-                 name_hint: str = "", prefault: bool = True):
+                 name_hint: str = ""):
         shm_cls = _shared_memory_cls()
         if slot_bytes % SLOT_ALIGN:
             slot_bytes += SLOT_ALIGN - slot_bytes % SLOT_ALIGN
@@ -339,17 +479,6 @@ class SlotRing:
         if shm is None:  # pragma: no cover
             raise RuntimeError("could not allocate a uniquely-named arena")
         self.shm = shm
-        # Pre-fault the whole arena once at create time: tmpfs pages are
-        # allocated HERE (startup, outside any measured steady window)
-        # instead of as major faults inside the workers' first framing
-        # passes — the difference between a warm ring and one that pays
-        # page-allocation latency for its first n_slots batches.
-        # ``prefault=False`` skips it (supervised respawns: the rebuild
-        # happens MID-RUN with the consumer waiting, so lazy faults —
-        # overlapped with worker framing — beat a serial multi-MB zero
-        # pass).
-        if prefault:
-            np.frombuffer(shm.buf, dtype=np.uint8)[:] = 0
         self._closed = False
         # Consumer-side generation ledger: descriptors MAPPED per slot
         # (the counterpart of SlotWriter._sent — see SlotFrame.generation).
@@ -388,12 +517,18 @@ class SlotRing:
                 f"negative shape (n={f.n_lines}, L={f.line_len}, "
                 f"payload={f.payload_len})",
             )
-        base = f.slot * self.slot_bytes
         n = max(f.n_lines, 1)
         buf_off, payload_off, total = slot_layout(
             n, f.line_len, f.payload_len
         )
-        if total > self.slot_bytes:
+        if f.segment:
+            if f.segment != segment_name(self.shm.name, f.slot):
+                raise RingFault(
+                    "descriptor",
+                    f"segment {f.segment!r} does not belong to slot "
+                    f"{f.slot}",
+                )
+        elif total > self.slot_bytes:
             raise RingFault(
                 "descriptor",
                 f"layout needs {total}B > slot_bytes={self.slot_bytes}",
@@ -406,7 +541,15 @@ class SlotRing:
                 "descriptor)",
                 stale=f.generation < expected,
             )
-        mv = self.shm.buf
+        if f.segment:
+            try:
+                mv, base = _attach_segment(f.segment, total), 0
+            except (OSError, ValueError) as e:
+                raise RingFault(
+                    "descriptor", f"segment {f.segment!r}: {e}"
+                ) from e
+        else:
+            mv, base = self.shm.buf, f.slot * self.slot_bytes
         lengths = np.frombuffer(
             mv, dtype=np.int32, count=n, offset=base
         )[: f.n_lines]
@@ -429,9 +572,14 @@ class SlotRing:
             slot_wait_s=f.slot_wait_s,
             ring=self,
             slot=f.slot,
+            segment=f.segment,
         )
 
     def release(self, slot: int) -> None:
+        """Take back ``slot``'s token, unlinking the segment framed for
+        it, if any: whoever holds the token owns the slot's segment name
+        (live mappings of it stay valid)."""
+        unlink_segment(segment_name(self.shm.name, slot))
         if not self._closed:
             try:
                 self.free_q.put(slot)
@@ -439,17 +587,20 @@ class SlotRing:
                 note_teardown_error(LOG, "SlotRing.release", e)
 
     def inplace_bytes(self, f: SlotFrame) -> int:
-        """Bytes this descriptor delivered through the arena instead of
-        the pipe (the feeder_ring_bytes_inplace_total increment)."""
+        """Bytes this descriptor delivered through shared memory (arena
+        or segment) instead of the pipe (the
+        feeder_ring_bytes_inplace_total increment)."""
         return 4 * f.n_lines + f.n_lines * f.line_len + f.payload_len
 
     def close(self) -> None:
-        """Unmap and unlink the segment.  Idempotent; outstanding
-        RingBatch views die with the mapping — callers must detach
-        batches that outlive the pool."""
+        """Unmap and unlink the arena, and unlink every slot's segment
+        name.  Idempotent; outstanding RingBatch views die with the
+        mapping — callers must detach batches that outlive the pool."""
         if self._closed:
             return
         self._closed = True
+        for slot in range(self.n_slots):
+            unlink_segment(segment_name(self.shm.name, slot))
         # mp.Queue's feeder thread would otherwise keep the process
         # alive waiting to flush released slot ids nobody will read.
         if hasattr(self.free_q, "cancel_join_thread"):

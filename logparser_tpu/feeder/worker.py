@@ -5,7 +5,8 @@ to worker ``i % N``) and pushes batch messages into its own queue.
 Backpressure is transport-specific but always producer-blocking:
 
 - **ring** transport (the default for process pools): the batch body is
-  framed directly into a shared-memory slot and only a tiny
+  framed directly into a shared-memory slot (or, when it outgrows the
+  slot, a segment of its own size leased under the slot) and only a tiny
   :class:`~logparser_tpu.feeder.ring.SlotFrame` descriptor crosses the
   queue — an exhausted free-slot queue blocks the worker until the
   consumer releases a slot;
@@ -215,8 +216,9 @@ def run_worker(
                     raise SlotOverflow("chaos: forced slot overflow")
                 n, L, overflow = writer.frame(chunk, line_len, slot)
             except SlotOverflow:
-                # This one batch outgrew the slot (pathological line
-                # bucket): give the slot back and ship it pickled.
+                # No shared memory for this one batch (its segment could
+                # not be reserved, or chaos forced it): give the slot
+                # back and ship it pickled.
                 writer.putback(slot)
             else:
                 from .ring import SlotFrame
@@ -229,6 +231,7 @@ def run_worker(
                     encode_s=time.perf_counter() - t0,
                     slot_wait_s=wait_s,
                     generation=writer.next_generation(slot),
+                    segment=writer.segment_of(slot),
                 )
                 if chaos is not None:
                     chaos.corrupt(desc)

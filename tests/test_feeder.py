@@ -490,13 +490,16 @@ def test_ring_exhaustion_blocks_producer_without_dropping():
 
 
 @pytestmark_ring
-def test_ring_slot_overflow_falls_back_to_pickle_per_batch():
-    """A batch that outgrows its slot ships over the pickled lane — the
-    stream stays complete and byte-identical, and the fallback is
-    counted (the ring degrades per batch, never wholesale)."""
+def test_ring_slot_overflow_goes_to_a_segment_per_batch():
+    """A batch that outgrows its slot is framed into a segment of its
+    own size — the stream stays complete and byte-identical, no batch
+    crosses pickled, and the segment batches are counted."""
+    from logparser_tpu.observability import metrics
+
     big = b"X" * 3000  # one line far beyond the tiny slot below
     blob = b"aaa\nbbb\n" + big + b"\nccc"
     ref_buf, ref_lengths, _ = encode_blob(blob, line_len=4096)
+    before = metrics().get("feeder_segment_batches_total")
     pool = _ring_pool(blob, workers=1, shard_bytes=1 << 20, batch_lines=1,
                       line_len=4096, slot_bytes=4096, ring_slots=2)
     ebs = list(pool.batches())
@@ -505,10 +508,217 @@ def test_ring_slot_overflow_falls_back_to_pickle_per_batch():
         np.concatenate([e.buf for e in ebs]), ref_buf
     )
     stats = pool.stats()
-    assert stats["pickle_fallback_batches"] >= 1
+    assert stats["pickle_fallback_batches"] == 0
+    assert stats["segment_batches"] == len(ebs) == 4
+    assert metrics().get("feeder_segment_batches_total") == before + 4
+
+
+def _own_segments():
+    """This process's ring names in /dev/shm (arenas and segments: every
+    name starts with the consumer's pid), so pools of other test
+    processes running alongside never read as leaks."""
+    from logparser_tpu.feeder import RING_NAME_PREFIX
+
+    mine = f"{RING_NAME_PREFIX}_{os.getpid()}_"
+    return {f for f in os.listdir("/dev/shm") if f.startswith(mine)}
+
+
+def _big_frame_blob(n=300):
+    """Lines framed at L = 4096: any batch of them, a shard's one-line
+    tail included, outgrows the 4 KiB slots the segment tests give
+    their rings."""
+    return b"\n".join(b"line %05d " % i + b"q" * (i % 97) for i in range(n))
+
+
+def _assert_frames_match(ebs, blob):
+    ref_buf, ref_lengths, _ = encode_blob(blob, line_len=4096)
+    assert b"".join(bytes(e.payload) for e in ebs) == blob
+    np.testing.assert_array_equal(np.concatenate([e.buf for e in ebs]),
+                                  ref_buf)
+    np.testing.assert_array_equal(np.concatenate([e.lengths for e in ebs]),
+                                  ref_lengths)
+
+
+@pytestmark_ring
+@pytest.mark.parametrize("use_processes", [False, True])
+def test_segment_batches_byte_identical_to_encode_blob(use_processes):
+    """Frames larger than their slot arrive through frame-sized segments
+    as zero-copy RingBatches whose buf, lengths and payload equal
+    encode_blob's, with no pickle fallback."""
     from logparser_tpu.observability import metrics
 
-    assert metrics().get("feeder_ring_pickle_fallback_total") >= 1
+    blob = _big_frame_blob()
+    before = metrics().get("feeder_segment_batches_total")
+    pool = _ring_pool(blob, shard_bytes=8000, line_len=4096,
+                      slot_bytes=4096, use_processes=use_processes)
+    ebs = []
+    for eb in pool.batches(detach=False):
+        assert isinstance(eb, RingBatch) and eb.segment
+        ebs.append(eb)
+    assert pool.stats()["mode"] == ("process" if use_processes else "thread")
+    assert pool.stats()["transport"] == "ring"
+    _assert_frames_match(ebs, blob)
+    for eb in ebs:
+        eb.release()
+    stats = pool.stats()
+    assert stats["pickle_fallback_batches"] == 0
+    assert stats["segment_batches"] == len(ebs) >= 2
+    assert metrics().get("feeder_segment_batches_total") == \
+        before + len(ebs)
+
+
+@pytestmark_ring
+def test_frame_that_fits_its_slot_takes_the_slot():
+    """The bypass case: a frame that fits its slot is framed into the
+    arena as before — no segment, none counted."""
+    from logparser_tpu.observability import metrics
+
+    blob = b"\n".join(b"short line %d" % i for i in range(500))
+    before = metrics().get("feeder_segment_batches_total")
+    pool = _ring_pool(blob, ring_slots=3)
+    ebs = []
+    for eb in pool.batches(detach=False):
+        assert isinstance(eb, RingBatch) and eb.segment == ""
+        ebs.append(bytes(eb.payload))
+        eb.release()
+    assert b"".join(ebs) == blob
+    assert pool.stats()["segment_batches"] == 0
+    assert metrics().get("feeder_segment_batches_total") == before
+
+
+@pytestmark_ring
+@pytest.mark.parametrize("event", ["close", "detach", "worker_crash"])
+def test_segments_leave_no_names_behind(event):
+    """No ring name survives: pool close; detached segment batches whose
+    views outlive the pool (and still read right); a supervised process
+    worker hard-killed mid-shard, whose replay keeps byte parity."""
+    if not os.path.isdir("/dev/shm"):
+        pytest.skip("no /dev/shm to observe")
+    from logparser_tpu.feeder import SupervisorPolicy
+
+    blob = _big_frame_blob(600)
+    kw = dict(shard_bytes=8000, line_len=4096, slot_bytes=4096)
+    before = _own_segments()
+    if event == "close":
+        pool = _ring_pool(blob, ring_slots=2, **kw)
+        it = pool.batches(detach=False)
+        next(it)  # one segment leased, the rest framed or in flight
+        it.close()
+        pool.close()
+    elif event == "detach":
+        pool = _ring_pool(blob, **kw)
+        ebs = list(pool.batches())
+        assert pool.stats()["segment_batches"] == len(ebs)
+        assert _own_segments() - before == set()
+        _assert_frames_match(ebs, blob)  # views outlive pool and names
+    else:
+        pool = _ring_pool(
+            blob, use_processes=True, workers=2,
+            chaos="kill_worker:worker=1:after=1:mode=hard",
+            policy=SupervisorPolicy(backoff_base_s=0.001,
+                                    backoff_max_s=0.01),
+            **kw,
+        )
+        _assert_frames_match(list(pool.batches()), blob)
+        assert pool.stats()["worker_restarts"] >= 1
+    assert _own_segments() - before == set()
+
+
+@pytestmark_ring
+def test_unsent_segment_of_a_stalled_worker_is_unlinked():
+    """A worker that framed a segment and stalls before sending it is
+    reaped past the deadline: the reap sweeps the slot's name, the
+    abandoned worker's give-back finds it gone, and the replay keeps
+    byte parity."""
+    if not os.path.isdir("/dev/shm"):
+        pytest.skip("no /dev/shm to observe")
+    from logparser_tpu.feeder import SupervisorPolicy
+
+    blob = _big_frame_blob(600)
+    before = _own_segments()
+    pool = _ring_pool(
+        blob, shard_bytes=8000, line_len=4096, slot_bytes=4096,
+        chaos="delay_put:worker=1:seconds=0.6",
+        policy=SupervisorPolicy(worker_deadline_s=0.15,
+                                backoff_base_s=0.001, backoff_max_s=0.01),
+    )
+    _assert_frames_match(list(pool.batches()), blob)
+    assert pool.stats()["worker_restarts"] >= 1
+    import time
+
+    time.sleep(0.8)  # the abandoned incarnation wakes and gives back
+    assert _own_segments() - before == set()
+
+
+@pytestmark_ring
+def test_segments_outstanding_never_exceed_ring_slots():
+    """The slot id stays the backpressure token for segments: with
+    every token leased the worker stops framing, so at most ring_slots
+    segments exist, and the stream completes once tokens come back."""
+    import threading
+
+    if not os.path.isdir("/dev/shm"):
+        pytest.skip("no /dev/shm to observe")
+    blob = _big_frame_blob(600)
+    before = _own_segments()
+    pool = _ring_pool(blob, workers=1, shard_bytes=1 << 20, line_len=4096,
+                      slot_bytes=4096, ring_slots=2)
+    it = pool.batches(detach=False)
+    held = [next(it), next(it)]
+    assert all(e.segment for e in held)
+    got = []
+    grabbed = threading.Event()
+
+    def grab():
+        got.append(next(it))
+        grabbed.set()
+
+    t = threading.Thread(target=grab, daemon=True)
+    t.start()
+    assert not grabbed.wait(0.4)  # no free token: the worker waits
+    assert len(_own_segments() - before) == 1 + pool.ring_slots  # + arena
+    held.pop(0).release()
+    assert grabbed.wait(5.0)
+    for eb in held + got:
+        eb.release()
+    n = 3
+    for eb in it:
+        assert len(_own_segments() - before) <= 1 + pool.ring_slots
+        eb.release()
+        n += 1
+    assert n == pool.stats()["segment_batches"] == len(split_batches(blob, 64))
+    assert _own_segments() - before == set()
+
+
+@pytestmark_ring
+def test_segment_reservation_enospc_takes_the_pipe(monkeypatch):
+    """A full tmpfs (ENOSPC from the page reservation) sends that batch
+    over the pickled lane, counted as a fallback, with parity kept and
+    no name left behind."""
+    import errno
+
+    from logparser_tpu.observability import metrics
+
+    def full(fd, offset, length):
+        raise OSError(errno.ENOSPC, "No space left on device")
+
+    monkeypatch.setattr(os, "posix_fallocate", full)
+    blob = _big_frame_blob()
+    before_seg = metrics().get("feeder_segment_batches_total")
+    before_fb = metrics().get("feeder_ring_pickle_fallback_total")
+    before = _own_segments() if os.path.isdir("/dev/shm") else None
+    pool = _ring_pool(blob, shard_bytes=8000, line_len=4096,
+                      slot_bytes=4096)
+    ebs = list(pool.batches())
+    _assert_frames_match(ebs, blob)
+    stats = pool.stats()
+    assert stats["pickle_fallback_batches"] == len(ebs) >= 2
+    assert stats["segment_batches"] == 0
+    assert metrics().get("feeder_ring_pickle_fallback_total") == \
+        before_fb + len(ebs)
+    assert metrics().get("feeder_segment_batches_total") == before_seg
+    if before is not None:
+        assert _own_segments() - before == set()
 
 
 @pytestmark_ring
