@@ -496,6 +496,12 @@ PIPELINE_STAGES = (
     "job_fold",          # one result folded into its shard's accumulator
     "job_commit",        # shard concat + write + manifest commit
     "feeder_close",      # worker joins + ring arena unlinks
+    # The served path (service.py, service_batching.py):
+    "serve_request",     # one LINES request, receipt to last byte sent
+    "coalesce_wait",     # an entry queued until a formed batch claims it
+    "coalesce_parse",    # one formed batch's parse on the dispatcher
+    "scatter",           # a batch result sliced back to its entries
+    "serve_send",        # the ARROW answer written to the socket
 )
 
 _ANNOTATE = {"enabled": _env_truthy("LOGPARSER_TPU_XPROF_STAGES")}

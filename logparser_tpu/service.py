@@ -112,6 +112,7 @@ from .observability import (
     log_warning_once,
     metrics,
     note_teardown,
+    pipeline_stage,
     suppressed_warning_counts,
 )
 from .tracing import (
@@ -985,8 +986,11 @@ class _SessionHandler(socketserver.BaseRequestHandler):
                     return
                 if lines_frame is None:
                     return  # end of session
-                if not self._serve_request(sock, parser, lines_frame, state,
-                                           send_stats):
+                # The request, from receipt to its last byte sent.
+                with pipeline_stage("serve_request"):
+                    served = self._serve_request(sock, parser, lines_frame,
+                                                 state, send_stats)
+                if not served:
                     return
         finally:
             self.server.key_session_exit(parser_key)
@@ -1123,7 +1127,8 @@ class _SessionHandler(socketserver.BaseRequestHandler):
 
         payload, count, oracle_rows, bad_lines = outcome
         try:
-            write_frame(sock, payload)
+            with pipeline_stage("serve_send", items=count):
+                write_frame(sock, payload)
         except OSError:
             return False
         dt = time.perf_counter() - t_request

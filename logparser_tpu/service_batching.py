@@ -80,7 +80,7 @@ import time
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional
 
-from .observability import log_warning_once, metrics
+from .observability import log_warning_once, metrics, pipeline_stage
 
 LOG = logging.getLogger(__name__)
 
@@ -126,11 +126,13 @@ class _Entry:
     the rendezvous the session thread blocks on.  State transitions are
     guarded by the owning batcher's lock: PENDING -> CLAIMED (dispatcher
     took it into a formed batch) or PENDING -> CANCELLED (deadline /
-    shutdown); CLAIMED entries always get ``result`` or ``error``."""
+    shutdown); CLAIMED entries always get ``result`` or ``error``.
+    ``claimed`` is set once the entry leaves PENDING for good (claimed,
+    or resolved while queued): the end of its ``coalesce_wait`` span."""
 
     __slots__ = ("blob", "count", "enq_t", "deadline_t", "max_wait_t",
-                 "event", "state", "result", "error", "abandoned",
-                 "trace_ctx")
+                 "claimed", "event", "state", "result", "error",
+                 "abandoned", "trace_ctx")
 
     PENDING, CLAIMED, CANCELLED = range(3)
 
@@ -153,6 +155,7 @@ class _Entry:
         # the straggler window it is willing to pay, without changing
         # parsing, sharing, shedding, or result bytes.
         self.max_wait_t = max_wait_t
+        self.claimed = threading.Event()
         self.event = threading.Event()
         self.state = _Entry.PENDING
         self.result: Any = None
@@ -289,15 +292,20 @@ class _KeyBatcher:
         return entry
 
     def wait(self, entry: _Entry, deadline_s: Optional[float]):
-        """Block the session thread until the entry's result/error.  On
-        deadline: cancel if still PENDING (the batch is not poisoned);
-        if already CLAIMED the batch is in flight — mark the entry
-        ABANDONED (recycling the lane once the whole in-flight batch
-        is), then wait it out: the session's own deadline machinery
-        answers the client and discards this late result, and this
-        worker thread keeps its in-flight slot until the parse truly
-        stops (wedge -> backpressure, docs/SERVICE.md)."""
-        if not entry.event.wait(deadline_s):
+        """Block the session thread until the entry's result/error; the
+        time until a formed batch claims it is the ``coalesce_wait``
+        span.  On deadline: cancel if still PENDING (the batch is not
+        poisoned); if already CLAIMED the batch is in flight — mark the
+        entry ABANDONED (recycling the lane once the whole in-flight
+        batch is), then wait it out: the session's own deadline
+        machinery answers the client and discards this late result, and
+        this worker thread keeps its in-flight slot until the parse
+        truly stops (wedge -> backpressure, docs/SERVICE.md)."""
+        end = None if deadline_s is None else time.monotonic() + deadline_s
+        with pipeline_stage("coalesce_wait", items=entry.count):
+            entry.claimed.wait(deadline_s)
+        left = None if end is None else max(0.0, end - time.monotonic())
+        if not entry.event.wait(left):
             with self.lock:
                 if entry.state == _Entry.PENDING:
                     entry.state = _Entry.CANCELLED
@@ -430,12 +438,14 @@ class _KeyBatcher:
                 )
                 reg.increment("service_coalesce_expired_total")
                 reg.gauge_add("service_coalesce_queue_depth", -1)
+                e.claimed.set()
                 e.event.set()
                 continue
             if claimed and total + e.count > self.co.max_lines:
                 break  # keep the batch inside the configured geometry
             self.queue.popleft()
             e.state = _Entry.CLAIMED
+            e.claimed.set()
             self.inflight.append(e)
             claimed.append(e)
             total += e.count
@@ -503,7 +513,14 @@ class _KeyBatcher:
         parse per formed batch, back-to-back batches overlapping upload
         with compute via ``parse_batch_stream``'s staged-H2D edge.
         Parser doubles without the streaming API take a plain
-        ``parse_blob`` per formed batch."""
+        ``parse_blob`` per formed batch.
+
+        Spans on this thread: ``coalesce_parse`` around each step of the
+        stream that yields one formed batch's result (the next batch's
+        formation and framing, ``encode``, included: the stream pulls it
+        to overlap its upload), holding the parser's shared stages; then
+        ``scatter`` around handing that result's windows to the waiting
+        entries."""
         parser = self.parser
         if not (hasattr(parser, "parse_batch_stream")
                 and hasattr(parser, "parse_encoded")):
@@ -511,8 +528,11 @@ class _KeyBatcher:
             while fb is not None:
                 fb.span = _begin_batch_span(fb)
                 try:
-                    self._scatter(fb, parser.parse_blob(
-                        fb.blob(), emit_views=False))
+                    with pipeline_stage("coalesce_parse", items=fb.total):
+                        result = parser.parse_blob(fb.blob(),
+                                                   emit_views=False)
+                    with pipeline_stage("scatter", items=fb.total):
+                        self._scatter(fb, result)
                 except Exception as e:  # noqa: BLE001 — relayed per entry
                     self._fail(fb, e)
                 finally:
@@ -529,14 +549,21 @@ class _KeyBatcher:
                     return
                 fb.span = _begin_batch_span(fb)
                 formed.append(fb)
-                yield fb.encoded()
+                with pipeline_stage("encode", items=fb.total):
+                    enc = fb.encoded()
+                yield enc
 
         try:
-            for result in parser.parse_batch_stream(gen(),
-                                                    emit_views=False):
+            stream = parser.parse_batch_stream(gen(), emit_views=False)
+            while True:
+                with pipeline_stage("coalesce_parse"):
+                    result = next(stream, None)
+                if result is None:
+                    break
                 fb = formed.popleft()
                 try:
-                    self._scatter(fb, result)
+                    with pipeline_stage("scatter", items=fb.total):
+                        self._scatter(fb, result)
                 except Exception as e:  # noqa: BLE001 — relayed per entry
                     # A partial scatter (e.g. a slice fault mid-batch)
                     # must still resolve EVERY entry of the popped batch
@@ -567,6 +594,7 @@ class _KeyBatcher:
             return
         entry.result = result
         entry.error = error
+        entry.claimed.set()
         with self.lock:
             # Off the recycle trigger's ledger BEFORE the event flips:
             # a resolved entry must never count toward "the whole

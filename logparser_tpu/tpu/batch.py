@@ -1455,10 +1455,14 @@ class TpuBatchParser:
         when a batch flags CSR overflow, so query-heavy corpora cost a few
         recompiles instead of routing every long line to the per-line
         oracle.  Returns False at the cap (those lines stay oracle-bound)."""
+        from ..observability import metrics
         from .pipeline import CSR_SLOTS_MAX
 
         if self.csr_slots >= CSR_SLOTS_MAX:
             return False
+        # Every warm shape of the old executor is lost: the next batch of
+        # each shape compiles (or loads) again on the caller's clock.
+        metrics().increment("parser_csr_growths_total")
         self.csr_slots *= 2
         for u in self.units:
             u.layout = PackedLayout.for_plans(u.plans, self.csr_slots)

@@ -653,11 +653,14 @@ CSR_SLOTS_MAX = 128
 # slot exhaustion.
 CSR_WINDOW_PER_SLOT = 8
 
-# Scan-window budget for the URI fast split (path + query + authority in
-# one span, so roomier than a lone query string): 12 bytes/slot puts the
-# default window at 192 — 2.6x the realistic corpus's longest URI — and
-# the CSR_SLOTS_MAX regrow at 1536, past any padded line bucket.
-URI_WINDOW_PER_SLOT = 12
+# Scan window of the URI fast split (path + query + authority in one
+# span), in bytes.  A line bucket of up to this many bytes is split
+# unwindowed (no URI can overflow it); a longer bucket scans this
+# window and routes the rare longer URI to the batched rescue.  Fixed,
+# not grown with the CSR slots: a growth rebuilds the executor, and the
+# first long URI would then compile every warm shape again on the
+# request path.
+URI_WINDOW = 1536
 
 # row 0 bit assignments (see compute_rows): bit 0 = line validity, bit 1 =
 # plausibility (multi-format winner protocol), bit 2 = CSR slot overflow,
@@ -1000,19 +1003,16 @@ def compute_rows(
                 uri = postproc.split_uri_fast(
                     b32, s, e, extract=extract, dash=dash,
                     need_authority=need_authority,
-                    window=URI_WINDOW_PER_SLOT * layout.csr_slots,
+                    window=URI_WINDOW,
                 )
                 uri_cache[cache_key] = uri
                 # Repair-needing URIs fail the line (unless the chain
                 # already produced nothing to repair).
                 line_constraints.append(uri["ok"] | ~ok)
-                # Span longer than the scan window: the same capacity
-                # defer as CSR slot exhaustion — raise the overflow bit
-                # (adaptive slot growth scales the window along) and
-                # fail the line so it rides the batched rescue.
-                uri_over = uri["overflow"] & ok
-                csr_overflow_rows.append(uri_over)
-                line_constraints.append(~uri_over)
+                # Span longer than the fixed scan window: fail the line
+                # so it rides the batched rescue.  No CSR overflow bit —
+                # more slots would not widen this window.
+                line_constraints.append(~(uri["overflow"] & ok))
             step_ok = ok & uri["ok"]
             if part == "path":
                 return (

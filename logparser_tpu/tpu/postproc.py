@@ -280,9 +280,7 @@ def split_uri_fast(
     there, and every positional output is rebased by the span start.  Rows
     whose span exceeds the window raise ``overflow`` (with ``ok`` held
     True so they route as a capacity defer, not a device reject); the
-    caller folds that into the adaptive CSR response — doubled slots scale
-    the window along, so long-URI corpora pay bounded recompiles, and at
-    the slot cap the rows stay oracle-bound.  Outputs are bit-identical to
+    caller routes those rows to the oracle.  Outputs are bit-identical to
     the unwindowed split for every row that fits.
     """
     B, L = buf.shape

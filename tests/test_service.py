@@ -22,8 +22,6 @@ from logparser_tpu.service import (
 )
 from logparser_tpu.tools.demolog import generate_combined_lines
 
-pytestmark = pytest.mark.slow
-
 FIELDS = [
     "IP:connection.client.host",
     "TIME.EPOCH:request.receive.time.epoch",
@@ -38,6 +36,7 @@ def service():
         yield svc
 
 
+@pytest.mark.slow
 def test_parse_round_trip(service):
     lines = generate_combined_lines(100, seed=41)
     with ParseServiceClient(
@@ -52,6 +51,7 @@ def test_parse_round_trip(service):
     assert all(isinstance(e, int) for e in epochs)
 
 
+@pytest.mark.slow
 def test_multiple_batches_one_session(service):
     with ParseServiceClient(
         service.host, service.port, "combined", FIELDS[:1]
@@ -61,6 +61,7 @@ def test_multiple_batches_one_session(service):
             assert table.num_rows == 10
 
 
+@pytest.mark.slow
 def test_bytes_and_str_lines(service):
     line = '9.8.7.6 - - [01/Jan/2026:00:00:00 +0000] "GET / HTTP/1.1" 200 5 "-" "x"'
     with ParseServiceClient(
@@ -71,6 +72,7 @@ def test_bytes_and_str_lines(service):
     assert t1.column(FIELDS[0]).to_pylist() == t2.column(FIELDS[0]).to_pylist() == ["9.8.7.6"]
 
 
+@pytest.mark.slow
 def test_bad_config_relays_error(service):
     with pytest.raises(ParseServiceError, match="bad config"):
         ParseServiceClient(
@@ -78,6 +80,7 @@ def test_bad_config_relays_error(service):
         ).parse(["x"])
 
 
+@pytest.mark.slow
 def test_bad_lines_are_nulls_not_errors(service):
     lines = ["complete garbage", "more garbage"]
     with ParseServiceClient(
@@ -89,6 +92,7 @@ def test_bad_lines_are_nulls_not_errors(service):
     assert table.column(FIELDS[0]).to_pylist() == [None, None]
 
 
+@pytest.mark.slow
 def test_parser_cache_shared_across_sessions(service):
     cache = service._server.parser_cache
     n_before = len(cache._parsers)
@@ -100,6 +104,7 @@ def test_parser_cache_shared_across_sessions(service):
     assert len(cache._parsers) == n_before  # same config -> same compiled parser
 
 
+@pytest.mark.slow
 def test_empty_batch_and_empty_line(service):
     # count-prefixed LINES framing: [] is a real (empty) batch, not
     # end-of-session, and an empty logline is a present-but-invalid row.
@@ -116,6 +121,7 @@ def test_empty_batch_and_empty_line(service):
         assert t2.num_rows == 3
 
 
+@pytest.mark.slow
 def test_embedded_newline_rejected(service):
     with ParseServiceClient(
         service.host, service.port, "combined", FIELDS[:1]
@@ -124,6 +130,7 @@ def test_embedded_newline_rejected(service):
             client.parse(["a\nb"])
 
 
+@pytest.mark.slow
 def test_shutdown_before_start_does_not_hang():
     svc = ParseService()
     svc.shutdown()  # must not block on the never-started serve_forever loop
@@ -154,6 +161,7 @@ def _feeder_session(monkeypatch, fail_with):
     return calls
 
 
+@pytest.mark.slow
 def test_feeder_death_degrades_to_error_free_arrow(monkeypatch):
     """A dead feeder fabric (FeederError) yields the SAME ARROW frame
     the inline path produces — no error frame, no RST — and the session
@@ -180,6 +188,7 @@ def test_feeder_death_degrades_to_error_free_arrow(monkeypatch):
     assert metrics().get("service_feeder_demotions_total") == before + 1
 
 
+@pytest.mark.slow
 def test_feeder_parse_failure_relays_error_frame_and_survives(monkeypatch):
     """A parse-shaped failure inside the feeder path relays a
     WELL-FORMED error frame (the client raises ParseServiceError, the
@@ -287,6 +296,7 @@ _RAW_CONFIG = json.dumps({
 }).encode()
 
 
+@pytest.mark.slow
 def test_session_shed_is_structured_busy():
     """Over the session budget a connection gets a structured BUSY frame
     with the server's retry hint — never a reset — and the slot frees
@@ -323,6 +333,7 @@ def test_session_shed_is_structured_busy():
                          labels={"reason": "sessions"}) > before
 
 
+@pytest.mark.slow
 def test_request_shed_inflight_session_survives():
     """Over the in-flight cap a REQUEST sheds BUSY but its session
     survives and the next request (after capacity frees) succeeds."""
@@ -344,6 +355,7 @@ def test_request_shed_inflight_session_survives():
             assert fast.parse(["b"]).num_rows == 1
 
 
+@pytest.mark.slow
 def test_backpressure_signal_sheds_requests(monkeypatch):
     """A saturated feeder fabric (queue_backpressure >= threshold) sheds
     per-request with reason=backpressure."""
@@ -360,6 +372,7 @@ def test_backpressure_signal_sheds_requests(monkeypatch):
             assert ei.value.reason == "backpressure"
 
 
+@pytest.mark.slow
 def test_pool_backpressure_fraction():
     """FeederPool.backpressure(): 0 before start/after close, rises when
     the consumer stalls against the bounded queue, and feeds the
@@ -382,6 +395,7 @@ def test_pool_backpressure_fraction():
     assert queue_backpressure() == 0.0
 
 
+@pytest.mark.slow
 def test_ring_backpressure_can_saturate():
     """Ring-transport occupancy is measured against REACHABLE capacity
     (slots, not the descriptor-queue bound + control slack), so a wedged
@@ -403,6 +417,7 @@ def test_ring_backpressure_can_saturate():
     pool.close()
 
 
+@pytest.mark.slow
 def test_zero_timeouts_disable_not_nonblocking():
     """idle/frame timeout 0 means DISABLED (like every other 0-disables
     knob), never non-blocking sockets that kill every session."""
@@ -417,6 +432,7 @@ def test_zero_timeouts_disable_not_nonblocking():
             assert client.parse(["x"]).num_rows == 1
 
 
+@pytest.mark.slow
 def test_request_deadline_yields_deadline_frame_and_survives():
     """An expired request answers a structured DEADLINE frame; the
     session survives and its next request succeeds.  With continuous
@@ -444,6 +460,7 @@ def test_request_deadline_yields_deadline_frame_and_survives():
     assert metrics().get("service_deadline_expired_total") > before
 
 
+@pytest.mark.slow
 def test_idle_timeout_closes_cleanly():
     before = metrics().get("service_timeouts_total",
                            labels={"kind": "idle"})
@@ -456,6 +473,7 @@ def test_idle_timeout_closes_cleanly():
                          labels={"kind": "idle"}) == before + 1
 
 
+@pytest.mark.slow
 def test_mid_frame_stall_times_out():
     before = metrics().get("service_timeouts_total",
                            labels={"kind": "frame"})
@@ -469,6 +487,7 @@ def test_mid_frame_stall_times_out():
                          labels={"kind": "frame"}) == before + 1
 
 
+@pytest.mark.slow
 def test_client_busy_retry_with_backoff():
     """The BUSY-aware client absorbs session sheds: reconnect + jittered
     backoff honoring the retry hint, then success once a slot frees."""
@@ -489,6 +508,7 @@ def test_client_busy_retry_with_backoff():
 #    close — never a traceback escaping the handler, never a hang. ---------
 
 
+@pytest.mark.slow
 def test_fuzz_truncated_config_frame(service):
     sock = socket.create_connection((service.host, service.port))
     sock.sendall(struct.pack(">I", 100) + b"ten bytes!")
@@ -500,6 +520,7 @@ def test_fuzz_truncated_config_frame(service):
         assert client.parse(["x"]).num_rows == 1
 
 
+@pytest.mark.slow
 def test_fuzz_oversized_length_prefix(service):
     """A hostile ~4 GiB length prefix costs one error frame (+ clean
     close), never an allocation."""
@@ -515,6 +536,7 @@ def test_fuzz_oversized_length_prefix(service):
         sock.close()
 
 
+@pytest.mark.slow
 def test_fuzz_non_json_config(service):
     sock = socket.create_connection((service.host, service.port))
     try:
@@ -529,6 +551,7 @@ def test_fuzz_non_json_config(service):
         sock.close()
 
 
+@pytest.mark.slow
 def test_fuzz_mid_frame_disconnect(service):
     sock = socket.create_connection((service.host, service.port))
     _send_frame(sock, _RAW_CONFIG)
@@ -540,6 +563,7 @@ def test_fuzz_mid_frame_disconnect(service):
         assert client.parse(["x"]).num_rows == 1
 
 
+@pytest.mark.slow
 def test_fuzz_zero_length_lines_frame(service):
     """A LINES frame shorter than its count header errors; the session
     survives to parse the next frame."""
@@ -557,6 +581,7 @@ def test_fuzz_zero_length_lines_frame(service):
         sock.close()
 
 
+@pytest.mark.slow
 def test_lines_payload_cap_discards_and_survives():
     """A LINES frame over the payload cap is consumed WITHOUT allocation,
     answered with an error frame, and the session survives."""
@@ -574,6 +599,7 @@ def test_lines_payload_cap_discards_and_survives():
                          labels={"reason": "lines_too_large"}) == before + 1
 
 
+@pytest.mark.slow
 def test_config_payload_cap():
     with ParseService(max_config_bytes=32) as svc:
         client = ParseServiceClient(
@@ -596,6 +622,7 @@ def _http_status(url):
         return e.code
 
 
+@pytest.mark.slow
 def test_graceful_drain_completes_admitted_requests():
     with ParseService(metrics_port=0, drain_deadline_s=10.0) as svc:
         _install_stub(svc, delay=0.3)
@@ -646,6 +673,7 @@ def test_graceful_drain_completes_admitted_requests():
                 if t.name.startswith("svc-sess-") and t.is_alive()]
 
 
+@pytest.mark.slow
 def test_note_teardown_counts_and_warns_once():
     from logparser_tpu.observability import note_teardown
     import logging
@@ -714,6 +742,7 @@ def _inject_parser(svc, config):
     svc._server.parser_cache._parsers[_ParserCache.key_of(config)] = parser
 
 
+@pytest.mark.slow
 def test_cross_session_coalesce_parity_bench_configs():
     """THE coalescing invariant (acceptance): for every wire-expressible
     bench config, K concurrent sessions pushing interleaved mixed-size
@@ -781,6 +810,7 @@ def test_cross_session_coalesce_parity_bench_configs():
     ).count - count0, "no batch ever coalesced >1 session"
 
 
+@pytest.mark.slow
 def test_deadline_expiry_while_queued():
     """An entry whose deadline expires while QUEUED behind a slow shared
     batch answers a structured DEADLINE (counted as a queue expiry) and
@@ -874,6 +904,7 @@ def _wait_lane_queue(svc, depth, deadline_s=5.0):
     raise AssertionError(f"no lane ever held {depth} queued entries")
 
 
+@pytest.mark.slow
 def test_coalesce_queue_feeds_admission_backpressure():
     """The coalescer's queue occupancy feeds queue_backpressure(): a
     saturated submission queue makes the ADMISSION tier shed
@@ -912,6 +943,7 @@ def test_coalesce_queue_feeds_admission_backpressure():
                 c.close()
 
 
+@pytest.mark.slow
 def test_shed_while_queued_coalesce_queue():
     """At coalesce_queue_depth the submission queue itself sheds a
     STRUCTURED BUSY{coalesce_queue} — coalescing must never reintroduce
@@ -957,6 +989,7 @@ def test_shed_while_queued_coalesce_queue():
                          labels={"reason": "coalesce_queue"}) == before + 1
 
 
+@pytest.mark.slow
 def test_drain_completes_queued_coalesce_entries():
     """A graceful drain finishes BOTH the in-flight shared batch and the
     entries still queued behind it — queued work belongs to admitted
@@ -1002,6 +1035,7 @@ def test_drain_completes_queued_coalesce_entries():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.slow
 def test_coalesce_window_end_takes_strictest_member():
     """Unit: the formation window is the configured end clamped by every
     claimed entry's own cap — the strictest session decides."""
@@ -1018,6 +1052,7 @@ def test_coalesce_window_end_takes_strictest_member():
     assert _KeyBatcher._window_end([free, tight, zero], default_end) == now
 
 
+@pytest.mark.slow
 def test_coalesce_hint_submit_and_queue_bound():
     """Unit: submit() stamps the cap from max_wait_s, and the bounded
     queue sheds identically with or without the hint."""
@@ -1044,6 +1079,7 @@ def test_coalesce_hint_submit_and_queue_bound():
         co.shutdown()
 
 
+@pytest.mark.slow
 def test_coalesce_wait_ms_zero_skips_straggler_window():
     """Wire: with a HUGE coalesce window and a second live session on
     the key (so the window would otherwise be paid), a session sending
@@ -1088,6 +1124,7 @@ def test_coalesce_wait_ms_zero_skips_straggler_window():
     )
 
 
+@pytest.mark.slow
 def test_coalesce_wait_ms_invalid_is_config_error():
     with ParseService() as svc:
         sock = socket.create_connection((svc.host, svc.port))
@@ -1106,6 +1143,7 @@ def test_coalesce_wait_ms_invalid_is_config_error():
 
 
 
+@pytest.mark.slow
 def test_sidecar_process_survives_successive_sessions():
     """The sidecar CLI's first pyarrow import used to happen on a session
     thread, and pyarrow 25's mimalloc pool then segfaulted the process on
@@ -1138,3 +1176,134 @@ def test_sidecar_process_survives_successive_sessions():
     finally:
         proc.kill()
         proc.wait(10)
+
+
+# -- the served path of the benchmark's sidecar cell ------------------------
+#
+# NGINX's predefined ``combined`` log_format with the fields the served
+# benchmark cell asks for (benchmark/configs/nginx_combined_sidecar.json).
+NGINX_COMBINED = ('$remote_addr - $remote_user [$time_local] "$request" '
+                  '$status $body_bytes_sent "$http_referer" "$http_user_agent"')
+SERVED_FIELDS = [
+    "IP:connection.client.host",
+    "TIME.STAMP:request.receive.time",
+    "HTTP.METHOD:request.firstline.method",
+    "HTTP.PATH:request.firstline.uri.path",
+    "HTTP.QUERYSTRING:request.firstline.uri.query",
+    "STRING:request.status.last",
+    "BYTES:response.body.bytes",
+]
+
+
+def _nginx_line(i, query_len, ua_len):
+    query = "&".join(f"k{j}=v{i}" for j in range(query_len // 8))
+    return (f'10.0.{i % 256}.{i // 256} - - [07/Mar/2026:10:00:00 +0000] '
+            f'"GET /p/{i}?{query} HTTP/1.1" 200 {i} "-" "{"u" * ua_len}"')
+
+
+def test_a_long_uri_keeps_every_warm_shape():
+    """A URI longer than the old adaptive scan window (192 bytes, then
+    twice that per CSR growth) used to rebuild the parser's executor:
+    every warm shape compiled again on the request path, and a served
+    sidecar stopped answering for seconds at the first long URI of a
+    window.  The URI window is now fixed, so a request of a warm shape
+    with a 600-byte URI compiles and loads nothing."""
+    warm = [_nginx_line(i, 8, 640) for i in range(40)]
+    long_uri = [_nginx_line(i, 600, 40) for i in range(40)]
+    assert max(map(len, warm)) // 128 == max(map(len, long_uri)) // 128
+    reg = metrics()
+    with ParseService() as svc, ParseServiceClient(
+        svc.host, svc.port, NGINX_COMBINED, SERVED_FIELDS, timeout=300
+    ) as client:
+        assert client.parse(warm).num_rows == len(warm)
+        compiles = reg.total("parser_compile_total")
+        table = client.parse(long_uri)
+        assert reg.total("parser_compile_total") == compiles
+        assert reg.total("parser_csr_growths_total") == 0
+    queries = table.column(SERVED_FIELDS[4]).to_pylist()
+    assert [len(q) for q in queries] == [
+        len(ln.split("?", 1)[1].split(" ", 1)[0]) + 1 for ln in long_uri]
+    assert all(table.column("__valid__").to_pylist())
+
+
+def test_a_coalesced_request_records_self_time_in_every_served_span(service):
+    """One coalesced LINES request moves the served path's spans: the
+    request (receipt to last byte), its wait in the coalescer queue,
+    the dispatcher's parse of the formed batch, the scatter back to the
+    entries and the socket write, each with self time of its own."""
+    stages = ("serve_request", "coalesce_wait", "coalesce_parse", "scatter",
+              "serve_send")
+    reg = metrics()
+
+    def self_s(stage):
+        return reg.get("stage_self_seconds_total", labels={"stage": stage})
+
+    def count(stage):
+        return reg.histogram("stage_seconds", labels={"stage": stage}).count
+
+    before = {s: (self_s(s), count(s)) for s in stages}
+    with ParseServiceClient(
+        service.host, service.port, "combined", FIELDS
+    ) as client:
+        assert client.parse(generate_combined_lines(64, seed=5)).num_rows == 64
+    # The client can hold its answer before the server's threads have
+    # closed scatter, serve_send and serve_request behind it.
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline and any(
+            self_s(s) <= before[s][0] or count(s) <= before[s][1]
+            for s in stages):
+        time.sleep(0.01)
+    for s in stages:
+        assert count(s) > before[s][1], s
+        assert self_s(s) > before[s][0], s
+    wait = reg.histogram("stage_seconds", labels={"stage": "coalesce_wait"})
+    req = reg.histogram("stage_seconds", labels={"stage": "serve_request"})
+    assert wait.sum < req.sum
+
+
+def test_served_answers_equal_the_benchmark_reference():
+    """Seeded weblog lines (the served benchmark cell's corpus, NGINX
+    dialect, a tiny pool) sent by three concurrent sessions, so the
+    coalescer shares batches among them: every answered row equals the
+    benchmark's plain reference (benchmark/reference.py, which imports
+    nothing of the program)."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench]
+    try:
+        import numpy as np
+        from gen.weblog import Corpus
+        from loadgen import compare
+        from reference import Reference
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(bench, "corpora", "weblog.json")) as f:
+        params = dict(json.load(f), pool_lines=400)
+    corpus = Corpus(params, 2**35 + 7, "nginx")
+    rng = np.random.default_rng(2**35 + 8)
+    lines_of = {}
+    for k in range(9):
+        idx = corpus.draw(rng, 50)
+        lines_of[k] = corpus.lines(idx, corpus.clock(
+            params["start_epoch_s"] + k, 50, 50))
+    answers = {}
+    with ParseService() as svc:
+        def session(ks):
+            with ParseServiceClient(svc.host, svc.port, NGINX_COMBINED,
+                                    SERVED_FIELDS, timeout=300) as client:
+                for k in ks:
+                    answers[k] = client.parse(lines_of[k]).to_pylist()
+
+        threads = [threading.Thread(target=session, args=(range(j, 9, 3),))
+                   for j in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(300)
+    ref = Reference(NGINX_COMBINED, SERVED_FIELDS)
+    got = compare(ref, lines_of, answers, len(lines_of), len(answers))
+    assert got["row_mismatches"] == 0, got["first_mismatch"]
+    assert got["unanswered"] == 0 and got["compared_lines"] == 450
