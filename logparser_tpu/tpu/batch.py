@@ -44,6 +44,7 @@ import logging as _logging
 _LOG = _logging.getLogger(__name__)
 from ..httpd.parser import HttpdLoglineParser
 from .pipeline import (
+    URI_WINDOW,
     FieldPlan,
     FormatUnit,
     PackedLayout,
@@ -2549,6 +2550,10 @@ class TpuBatchParser:
                 "device_dispatch_total",
                 labels={"views": "on" if views_on else "off"},
             )
+            if buf.shape[1] > URI_WINDOW and any(
+                    u.splits_uri for u in self.units):
+                # The URI split scans its fixed window of each line.
+                metrics().increment("uri_window_lines_total", B)
             with pipeline_stage("device", items=B):
                 try:
                     if staged is not None:

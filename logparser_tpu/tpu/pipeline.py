@@ -643,14 +643,14 @@ CSR_SLOTS = 16
 CSR_SLOTS_MAX = 128
 
 # CSR scan-window budget, in span bytes per segment slot.  split_csr runs
-# its scans over a compact [B, slots * CSR_WINDOW_PER_SLOT] gather of the
-# span instead of the full padded line — spans (query strings, cookie
-# headers) are tiny next to L, and the scans are the kernel's dominant
-# cost.  A span longer than the window raises the same CSR_OVERFLOW_BIT
-# as running out of slots, and the same adaptive response (double the
-# slots, window scales along) resolves it; at CSR_SLOTS_MAX the window
-# covers 1024 bytes and longer spans stay oracle-bound, exactly like
-# slot exhaustion.
+# its scans over a compact [B, slots * CSR_WINDOW_PER_SLOT] window cut
+# out of the span by the log-shift alignment, instead of the full padded
+# line — spans (query strings, cookie headers) are tiny next to L, and
+# the scans are the kernel's dominant cost.  A span longer than the
+# window raises the same CSR_OVERFLOW_BIT as running out of slots, and
+# the same adaptive response (double the slots, window scales along)
+# resolves it; at CSR_SLOTS_MAX the window covers 1024 bytes and longer
+# spans stay oracle-bound, exactly like slot exhaustion.
 CSR_WINDOW_PER_SLOT = 8
 
 # Scan window of the URI fast split (path + query + authority in one
@@ -1235,6 +1235,7 @@ def compute_rows(
                 # (nginx $args) and cookies are raw header text: no.
                 uri_encoded=bool(plan.steps) and plan.steps[-1][0] == "uri",
                 window=CSR_WINDOW_PER_SLOT * layout.csr_slots,
+                extract=extract,
             )
             if not plan.steps:
                 # Direct token capture of the query string: CLF null ->
@@ -1338,6 +1339,15 @@ class FormatUnit:
     # plausibility bit — the valid bit stays 0, so it can never claim a
     # line, only contest later formats' claims.
     plausibility_only: bool = False
+
+    @property
+    def splits_uri(self) -> bool:
+        """Whether :func:`compute_rows` runs the URI split for this unit:
+        some device plan chains a ``uri`` step."""
+        return any(
+            p.kind != "host" and any(name == "uri" for name, _ in p.steps)
+            for p in self.plans
+        )
 
     def plan_for(self, field_id: str) -> FieldPlan:
         for p in self.plans:

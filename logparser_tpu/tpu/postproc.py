@@ -276,26 +276,25 @@ def split_uri_fast(
     normalization) — the ``amp`` flag tells the materializer to swap it.
 
     ``window`` bounds the scan domain exactly as in :func:`split_csr`: the
-    URI span is gathered into a compact [B, window] buffer, the split runs
-    there, and every positional output is rebased by the span start.  Rows
-    whose span exceeds the window raise ``overflow`` (with ``ok`` held
-    True so they route as a capacity defer, not a device reject); the
-    caller routes those rows to the oracle.  Outputs are bit-identical to
-    the unwindowed split for every row that fits.
+    URI span is cut into a compact [B, window] buffer by ``extract`` (the
+    log-shift alignment, no per-byte gather), the split runs there, and
+    every positional output is rebased by the span start.  Rows whose
+    span exceeds the window raise ``overflow`` (with ``ok`` held True so
+    they route as a capacity defer, not a device reject); the caller
+    routes those rows to the oracle.  Outputs are bit-identical to the
+    unwindowed split for every row that fits: the split reads no byte at
+    or past the span's end, where the window holds zeros.
     """
     B, L = buf.shape
+    extract = extract or gather_span_bytes
     if window is not None and int(window) < L:
         W = int(window)
         span = end - start
-        widx = jnp.clip(
-            start[:, None] + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1),
-            0, L - 1,
-        )
-        wbuf = jnp.take_along_axis(buf, jnp.broadcast_to(widx, (B, W)), axis=1)
         res = split_uri_fast(
-            wbuf,
+            extract(buf, start, W),
             jnp.zeros_like(start),
             jnp.minimum(span, W),
+            extract=extract,
             dash=dash,
             need_authority=need_authority,
         )
@@ -306,7 +305,6 @@ def split_uri_fast(
         res["ok"] = res["ok"] | over
         res["overflow"] = over
         return res
-    extract = extract or gather_span_bytes
     pos = jax.lax.broadcasted_iota(jnp.int32, (B, L), 1)
     in_span = (pos >= start[:, None]) & (pos < end[:, None])
     width = end - start
@@ -644,6 +642,7 @@ def split_csr(
     kv: int = ord("="),
     uri_encoded: bool = False,
     window: Optional[int] = None,
+    extract=None,
 ) -> Dict[str, object]:
     """CSR segment split of spans on device: the vectorized core of the
     wildcard dissectors (QueryStringFieldDissector.java:76-108 splits on
@@ -661,27 +660,24 @@ def split_csr(
     compaction on a SIMD machine would cost a sort, skipping on host costs
     nothing.
 
-    ``window`` bounds the scan domain: the span bytes are gathered into a
-    compact [B, window] buffer and every [.,L]-wide plane above shrinks to
-    [., window] — the scans are the kernel cost, and spans (query strings,
-    cookie headers) are tiny next to the padded line length.  Rows whose
-    span exceeds the window raise ``overflow`` — the same exact capacity
-    defer as running out of slots, and the caller's adaptive response
-    (double the slots, which callers scale the window by) resolves both.
-    Windowed outputs are bit-identical to the unwindowed split for every
-    row that fits: the core sees the same span bytes at a rebased origin.
+    ``window`` bounds the scan domain: ``extract`` (the log-shift
+    alignment of :func:`gather_span_bytes` unless the caller passes its
+    own) cuts the span bytes into a compact [B, window] buffer, and every
+    [.,L]-wide plane above shrinks to [., window] — the scans are the
+    kernel cost, and spans (query strings, cookie headers) are tiny next
+    to the padded line length.  Rows whose span exceeds the window raise
+    ``overflow`` — the same exact capacity defer as running out of slots,
+    and the caller's adaptive response (double the slots, which callers
+    scale the window by) resolves both.  Windowed outputs are
+    bit-identical to the unwindowed split for every row that fits: the
+    core sees the same span bytes at a rebased origin.
     """
     B, L = buf.shape
     if window is not None and int(window) < L:
         W = int(window)
         span = end - start
-        widx = jnp.clip(
-            start[:, None] + jax.lax.broadcasted_iota(jnp.int32, (1, W), 1),
-            0, L - 1,
-        )
-        wbuf = jnp.take_along_axis(buf, jnp.broadcast_to(widx, (B, W)), axis=1)
         res = split_csr(
-            wbuf,
+            (extract or gather_span_bytes)(buf, start, W),
             jnp.zeros_like(start),
             jnp.minimum(span, W),
             max_segments,

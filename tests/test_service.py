@@ -1212,10 +1212,23 @@ def test_a_long_uri_keeps_every_warm_shape():
     long_uri = [_nginx_line(i, 600, 40) for i in range(40)]
     assert max(map(len, warm)) // 128 == max(map(len, long_uri)) // 128
     reg = metrics()
+
+    def walks():
+        return (reg.total("parser_prewarm_runs_total")
+                + reg.total("parser_prewarm_errors_total"))
+
+    walks0 = walks()
     with ParseService() as svc, ParseServiceClient(
         svc.host, svc.port, NGINX_COMBINED, SERVED_FIELDS, timeout=300
     ) as client:
         assert client.parse(warm).num_rows == len(warm)
+        # The new parser's shape ladder compiles on the service's prewarm
+        # thread, off the request path, and may still be walking when
+        # the first answer lands: count from the end of that walk.
+        deadline = time.monotonic() + 300
+        while walks() == walks0 and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert walks() > walks0
         compiles = reg.total("parser_compile_total")
         table = client.parse(long_uri)
         assert reg.total("parser_compile_total") == compiles
