@@ -9,7 +9,7 @@ PY ?= python
 SMOKE_TIMEOUT ?= 600
 SMOKE = timeout -k 10 $(SMOKE_TIMEOUT)
 
-.PHONY: test test-fast metrics-smoke feeder-smoke chaos-smoke rescue-smoke service-smoke coalesce-smoke fleet-smoke job-smoke pod-smoke device-smoke warm-smoke agg-smoke trace-smoke bench native clean
+.PHONY: test test-fast metrics-smoke feeder-smoke chaos-smoke rescue-smoke service-smoke coalesce-smoke fleet-smoke job-smoke pod-smoke device-smoke warm-smoke agg-smoke trace-smoke native clean
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -163,9 +163,6 @@ trace-smoke:
 lint:
 	$(PY) -m ruff check logparser_tpu tests
 	$(PY) -m mypy logparser_tpu --no-error-summary
-
-bench:
-	$(PY) bench.py
 
 # Build the C++ host tier (ctypes library); falls back to numpy when absent.
 native:

@@ -143,7 +143,7 @@ class Token:
             # Once per process, not once per format assembly: every parser
             # build (oracle + metadata + per-worker instances) re-emits
             # identical token warnings — e.g. "Only some parts of localized
-            # timestamps are supported" spamming the bench/multichip tails.
+            # timestamps are supported" spamming every run's log.
             # Repeats are counted (observability.suppressed_warning_counts).
             log_warning_once(logging.getLogger(__name__), message)
 

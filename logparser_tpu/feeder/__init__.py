@@ -2,7 +2,7 @@
 
 Turns raw log sources into a steady, ordered stream of framed,
 device-ready batches so the device tier is never input-starved
-(docs/FEEDER.md; BASELINE.md's 83 GB/s feed question).  Three layers:
+(docs/FEEDER.md).  Three layers:
 
 - :mod:`~logparser_tpu.feeder.shards` — byte-range shard planning with
   newline-boundary healing (the reference InputFormat's split

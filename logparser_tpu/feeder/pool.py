@@ -321,7 +321,7 @@ class FeederPool:
       (``spawn`` where unavailable): the parent may hold an initialized
       device runtime, which plain ``fork`` would duplicate into
       children that must never touch the chip, and ``spawn`` re-runs
-      ``__main__`` (bench/driver scripts would re-import heavily).
+      ``__main__`` (driver scripts would re-import heavily).
     - ``worker_delay_s``: per-batch producer sleep (shaping/test hook).
     - ``supervise``: worker supervision (default True — crashes are
       recovered, poison shards quarantined, transports demoted; see

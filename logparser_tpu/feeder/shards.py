@@ -1,7 +1,7 @@
 """Shard planner: byte-range shards with newline-boundary healing.
 
-The multichip projection in BASELINE.md needs ~83 GB/s of input feed —
-far beyond one reader thread — so the corpus must be split into
+Feeding the device at its kernel rate takes far more than one reader
+thread can frame, so the corpus must be split into
 independent byte ranges that many workers can frame in parallel.  The
 split semantics mirror the reference's Hadoop InputFormat
 (ApacheHttpdLogfileInputFormat + LineRecordReader): raw shards tile the

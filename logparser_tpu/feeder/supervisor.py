@@ -94,7 +94,7 @@ class SupervisorPolicy:
     #: declared stalled and respawned.  None disables (default): with a
     #: slow consumer holding slot leases, a stalled-looking worker may
     #: just be backpressured — enable it when the consumer is known to
-    #: release promptly (the chaos/bench harnesses do).
+    #: release promptly (the chaos harness does).
     worker_deadline_s: Optional[float] = None
 
 

@@ -56,9 +56,9 @@ device/core budget), with:
   tier already sheds on.
 
 Drilled by ``make fleet-smoke`` (``tools/fleet_smoke.py``: a 1-of-3
-hard kill and a live rolling restart under loadgen traffic) and gated
-in ``bench.py``'s ``fleet`` section (goodput scaling 1->N, kill-drill
-retention); chaos primitives ``kill_sidecar``/``wedge_sidecar``/
+hard kill and a live rolling restart under loadgen traffic) and tested
+in ``tests/test_front.py`` (failover, rolling restart, byte parity with
+a solo service); chaos primitives ``kill_sidecar``/``wedge_sidecar``/
 ``flap_sidecar`` (``tools/chaos.py``) produce the failures on purpose.
 docs/SERVICE.md "Fleet" is the ops runbook.
 """
@@ -292,7 +292,7 @@ class FrontSupervisor:
 
 # ---------------------------------------------------------------------------
 # sidecar handles: one supervised worker process (or an in-process
-# stand-in for tests/bench)
+# stand-in for tests)
 # ---------------------------------------------------------------------------
 
 
@@ -414,12 +414,12 @@ class ProcessSidecar:
 
 
 class LocalSidecar:
-    """In-process sidecar stand-in (tests, and the bench's 1-sidecar
-    reference): a real :class:`~logparser_tpu.service.ParseService` in
-    THIS process, fronted over real sockets exactly like a child
-    process would be.  ``kill()`` force-closes it (connections die
-    mid-frame — the crash shape); ``suspend()`` stops its metrics
-    endpoint (health probes go silent — the wedge shape)."""
+    """In-process sidecar stand-in (tests): a real
+    :class:`~logparser_tpu.service.ParseService` in THIS process,
+    fronted over real sockets exactly like a child process would be.
+    ``kill()`` force-closes it (connections die mid-frame — the crash
+    shape); ``suspend()`` stops its metrics endpoint (health probes go
+    silent — the wedge shape)."""
 
     def __init__(self, index: int, **service_kwargs: Any):
         from .service import ParseService
@@ -849,8 +849,8 @@ class _FrontSessionHandler(socketserver.BaseRequestHandler):
 class FrontTier:
     """The replicated front tier (module docstring; docs/SERVICE.md
     "Fleet").  ``spawner(index) -> handle`` builds one sidecar — the
-    default spawns :class:`ProcessSidecar` children; tests and the
-    bench inject :class:`LocalSidecar` (or stubs).  ``sidecar_args``
+    default spawns :class:`ProcessSidecar` children; tests inject
+    :class:`LocalSidecar` (or stubs).  ``sidecar_args``
     ride every default-spawned child's CLI (version/config swaps roll
     through :meth:`roll`)."""
 

@@ -10,8 +10,8 @@ files through the atomic :class:`~logparser_tpu.jobs.writer.JobWriter`,
 committed one at a time into the JSON manifest
 (:mod:`~logparser_tpu.jobs.manifest`).
 
-Durability contract (the kill-drill invariant, gated in ``bench.py``
-and drilled by ``make job-smoke``):
+Durability contract (the kill-drill invariant, drilled by ``make
+job-smoke`` and tested in ``tests/test_jobs.py``):
 
 - a shard is COMMITTED exactly when its manifest entry exists; its
   files were renamed into place (and fsynced) strictly before;
@@ -156,7 +156,7 @@ class JobPolicy:
 
     io_retries: int = 3          # writer attempts = io_retries + 1
     io_backoff_s: float = 0.05   # backoff base, doubling per retry
-    # Crash simulation for tests/bench: abandon the run (WITHOUT
+    # Crash simulation for tests: abandon the run (WITHOUT
     # committing anything further) after this many shard commits this
     # run — models a kill landing on a commit boundary; the real
     # SIGKILL drill lives in tools/job_smoke.py.
@@ -291,10 +291,10 @@ def run_job(
     """Run (or resume) one durable job.  See module docstring.
 
     ``parser`` lets a caller reuse a compiled ``TpuBatchParser`` (its
-    config must match the spec — bench/smoke reuse the session parser
-    to keep jit compiles out of timed windows).  ``chaos`` arms fault
-    injection (``ChaosSpec`` / grammar string; default: the
-    ``LOGPARSER_TPU_CHAOS`` env var)."""
+    config must match the spec — the benchmark's ETL driver and the
+    smokes reuse one parser to keep jit compiles out of timed
+    windows).  ``chaos`` arms fault injection (``ChaosSpec`` / grammar
+    string; default: the ``LOGPARSER_TPU_CHAOS`` env var)."""
     # Root span: every phase span below nests under it, so its self
     # time is the part of the job that no phase covers.  Kept off the
     # profiler track: a trace names each idle gap after the annotation

@@ -24,9 +24,10 @@ New work:
   lock-guarded histogram update per stage per batch — never per line), so
   disabled-consumer overhead is negligible.  ``service.py`` renders it as a
   Prometheus ``/metrics`` endpoint and an optional per-request STATS frame;
-  ``bench.py`` consumes the same :meth:`MetricsRegistry.stage_breakdown`
-  definitions for its delivery report, so live serving and the bench speak
-  identical stage names (docs/OBSERVABILITY.md is the inventory).
+  the benchmark's per-layer readers (``benchmark/readers.py``) take window
+  deltas of the same ``stage_seconds`` histograms and counters, so live
+  serving and the benchmark speak identical stage names
+  (docs/OBSERVABILITY.md is the inventory).
 - :func:`pipeline_stage` — the one span primitive: inclusive time into the
   ``stage_seconds`` histogram, self time (minus the stage's children on
   the same thread) into ``stage_self_seconds_total``, and an optional
@@ -84,7 +85,7 @@ DEFAULT_TIME_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
-# Row-count buckets for batch-size histograms (the bench/service batch
+# Row-count buckets for batch-size histograms (the job/service batch
 # spectrum: record-reader micro-batches up to the 64k headline and beyond).
 BATCH_ROWS_BUCKETS: Tuple[float, ...] = (
     64, 256, 1024, 4096, 16384, 65536, 262144,
@@ -250,7 +251,7 @@ class MetricsRegistry:
     One instance is the process-wide spine (:func:`metrics`): the batch
     pipeline, the host pool, the Arrow bridge and the sidecar service all
     write into it; ``service.py``'s ``/metrics`` endpoint and STATS frames
-    and ``bench.py``'s delivery breakdown all read from it — same metric
+    and the benchmark's per-layer readers all read from it — same metric
     definitions everywhere.  All updates are batch-granularity (hot loops
     never touch it per line) and lock-guarded (service threads are
     concurrent)."""
@@ -345,8 +346,8 @@ class MetricsRegistry:
     def stage_breakdown(self) -> Dict[str, Dict[str, Any]]:
         """Per-pipeline-stage summary from the ``stage_seconds`` histograms
         (+ the ``stage_items_total`` counters): the SINGLE definition the
-        /metrics endpoint, the STATS frame and bench.py's delivery section
-        all derive from — same stage names everywhere."""
+        /metrics endpoint and the STATS frame both derive from — same
+        stage names everywhere."""
         with self._lock:
             hists = [
                 (lb, h) for (n, lb), h in self._hists.items()
@@ -458,7 +459,7 @@ def metrics() -> MetricsRegistry:
 
 def counter_sum(name: str) -> float:
     """Total of one counter family across all label sets — the drills'
-    "did this family move" helper (bench/smoke/tests share it so the
+    "did this family move" helper (smokes and tests share it so the
     formatted-series key shape has one consumer-side home)."""
     return sum(
         v for k, v in _GLOBAL_METRICS.as_dict().items()
@@ -472,8 +473,9 @@ def counter_sum(name: str) -> float:
 # ---------------------------------------------------------------------------
 
 # Canonical hot-path stage names (docs/OBSERVABILITY.md): the batch pipeline
-# emits exactly these via pipeline_stage/observe_stage; bench.py's delivery
-# breakdown and tools/profile_device.py host scopes reuse them verbatim.
+# emits exactly these via pipeline_stage/observe_stage; the benchmark's
+# per-layer readers (benchmark/metrics/) and tools/profile_device.py host
+# scopes reuse them verbatim.
 PIPELINE_STAGES = (
     "encode",            # [B, L] uint8 packing (native framer / per-line)
     "h2d_stage",         # staged async upload enqueue (stream double-buffer)

@@ -10,7 +10,7 @@
    ``n_hosts``/``host_index`` set), committing into its per-host
    manifest — subprocesses by default (the simulated-pod shape: real
    deployments run the same CLI on real hosts against a shared
-   filesystem), or inline in-process for tests and the bench;
+   filesystem), or inline in-process for tests;
 3. a host that dies or fails is relaunched up to
    ``PodPolicy.host_retries`` times — resume semantics make this free
    (its committed shards are skipped; only the uncommitted tail of its
@@ -23,7 +23,8 @@ The kill-drill invariant, one level up from the single-host one: SIGKILL
 any host mid-job, rerun ``run_pod`` (or resume the one host), and the
 merged output is byte-identical to an undisturbed single-host run, with
 committed shards never re-parsed — drilled live in
-``tools/pod_smoke.py`` and gated in bench's ``pod`` section.
+``tools/pod_smoke.py`` and tested in ``tests/test_pod.py``
+(``test_host_loss_resume_byte_parity``).
 """
 from __future__ import annotations
 

@@ -612,7 +612,8 @@ class _PrewarmWorker:
                 reg.increment("parser_prewarm_seconds_total",
                               time.perf_counter() - t0)
                 # One tick per completed parser walk: pollable by smokes
-                # and the bench ("is the ladder warm yet?") where the
+                # and the benchmark's sidecar driver ("is the ladder warm
+                # yet?") where the
                 # seconds/shapes counters alone cannot distinguish one
                 # finished walk from one still in flight.
                 reg.increment("parser_prewarm_runs_total", 1)
@@ -658,7 +659,7 @@ class _ServiceServer(socketserver.ThreadingTCPServer):
         # Cross-session batch coalescer (service_batching.py), attached
         # by ParseService when limits.coalesce is on; None = every
         # request dispatches its own device batch (the pre-round-14
-        # behavior, and the bench A/B baseline).
+        # behavior).
         self.coalescer: Optional[Any] = None
 
     def admitted_sessions(self) -> int:
@@ -1139,8 +1140,8 @@ class _SessionHandler(socketserver.BaseRequestHandler):
             req_span.end(outcome="ok", lines=count)
         if send_stats:
             # STATS frame: per-request figures + the SAME
-            # process-cumulative stage breakdown /metrics and
-            # bench.py report (one metric definition everywhere).
+            # process-cumulative stage breakdown /metrics reports
+            # (one metric definition everywhere).
             stats = {
                 "v": 1,
                 "request": {

@@ -1,7 +1,7 @@
 """Synthetic access-log generator (the demolog equivalent).
 
 The reference ships a 3456-line real ``combined`` access log
-(examples/demolog/hackers-access.log) as golden/bench data.  We generate a
+(examples/demolog/hackers-access.log) as golden/demo data.  We generate a
 deterministic synthetic corpus with the same statistical shape instead:
 realistic IPs, increasing timestamps, encoded + messy query strings, CLF null
 bytes, quoted user agents, and a configurable fraction of hostile lines.
@@ -82,9 +82,10 @@ def generate_combined_lines(
 def truncate_to_common(line: str) -> str:
     """Strip the quoted referer/user-agent tail off a combined line,
     yielding a common-format (`%h %l %u %t "%r" %>s %b`) line.  The ONE
-    definition of the combined->common derivation — bench.py's
-    multiformat corpus and the loadgen's mixed-format drill both use it,
-    so their corpora can never silently diverge."""
+    definition of the combined->common derivation — the tests'
+    ``multiformat_mixed`` corpus (``tests/_configs.py``) and the
+    loadgen's mixed-format drill both use it, so their corpora can never
+    silently diverge."""
     try:
         cut = line.rindex(' "', 0, line.rindex(' "'))
         return line[:cut]
@@ -102,8 +103,9 @@ def write_demolog(
     return len(lines)
 
 
-# The benchmark-of-record field set (bench.py and the device profiler
-# both import it, so they can never measure different parsers).
+# The headline ``combined`` field set: the tests' ``combined`` config
+# (``tests/_configs.py``) and the device profiler
+# (``tools/profile_device.py``) both import it.
 HEADLINE_FIELDS = [
     "IP:connection.client.host",
     "STRING:connection.client.user",

@@ -2,7 +2,7 @@
 
 The reference ships generated test databases
 (GeoIP2-TestData/source-data/*.json rendered by write-test-data.pl); the
-rebuild's GeoIP tests and bench config used that read-only checkout.  This
+rebuild's GeoIP tests used that read-only checkout.  This
 module removes the dependency: a minimal writer for the public MaxMind DB
 file format spec v2.0 (the exact inverse of
 :mod:`logparser_tpu.geoip.mmdb`) plus generators for the City / Country /
@@ -194,7 +194,7 @@ class MMDBWriter:
         )
 
     def write(self, path: str) -> str:
-        # Atomic: a concurrent reader (bench + pytest racing to generate
+        # Atomic: a concurrent reader (two test processes racing to generate
         # the shared fixtures) must never see a half-written file.
         tmp = f"{path}.tmp{os.getpid()}"
         with open(tmp, "wb") as f:
@@ -204,7 +204,7 @@ class MMDBWriter:
 
 
 # ---------------------------------------------------------------------------
-# Fixture records: the Basjes test ranges the suite (and bench) assert on.
+# Fixture records: the Basjes test ranges the suite asserts on.
 # ---------------------------------------------------------------------------
 
 

@@ -14,8 +14,8 @@ an SLO cares about it:
   server-side, session survived).
 - ``errors``        — ordinary per-request error frames.
 - ``resets``        — the FORBIDDEN outcome: a connection that died where
-  a response frame was due (RST/EOF).  The bench gate holds this at 0
-  under a 2x overload burst.
+  a response frame was due (RST/EOF).  ``make service-smoke`` holds
+  this at 0 under an overload burst.
 
 Arrival model: each client schedules bursts of ``burst`` back-to-back
 requests every ``interval_s`` on the wall clock.  When the service is
@@ -24,8 +24,7 @@ immediately — the backlog IS the overload — which is the open-loop
 property closed-loop harnesses lack (they politely slow down with the
 server and hide the melt).
 
-Used three ways: ``bench.py``'s ``service`` section (goodput-retention +
-zero-reset gates), ``tools/service_smoke.py`` (CI), and standalone::
+Used two ways: ``tools/service_smoke.py`` (CI) and standalone::
 
     python -m logparser_tpu.tools.loadgen --port 8123 --clients 8
 """

@@ -3,9 +3,7 @@
 ``jax.profiler.trace`` captures the chip this process owns, and the
 xplane protobuf is parseable with the in-image tensorflow (
 ``tensorflow.tsl.profiler.protobuf.xplane_pb2``), so this tool runs the
-fused executor under the profiler and prints per-fusion device time —
-ground truth the marginal-slope estimator in bench.py cannot give
-(it is jitter- and floor-limited; see ROADMAP).
+fused executor under the profiler and prints per-fusion device time.
 
 Set LOGPARSER_TPU_XPROF_STAGES=1 (or call
 ``logparser_tpu.enable_stage_annotations()``) before capturing and the

@@ -46,8 +46,7 @@ import time
 RESCUE_RATE_FLOOR = float(os.environ.get(
     "LOGPARSER_TPU_RESCUE_SMOKE_RATE_FLOOR", "5000"))
 # Whole-batch effective floor — deliberately conservative: the smoke
-# runs on CI CPUs; the real >=5M gate is bench.py's RESCUE_EFFECTIVE
-# floor on the TPU host.
+# runs on CI CPUs.
 EFFECTIVE_FLOOR = float(os.environ.get(
     "LOGPARSER_TPU_RESCUE_SMOKE_EFFECTIVE_FLOOR", "2000"))
 

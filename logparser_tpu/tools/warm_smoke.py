@@ -114,8 +114,7 @@ def boot_probe(cache_dir: str, *, lines: Sequence[str],
     Returns ``ready_s`` (spawn -> SIDECAR_READY), ``first_request_s``
     (CONFIG+LINES -> ARROW wall, parser build included), ``arrow`` (the
     payload bytes, for cross-boot parity), ``prewarm_done``, the counter
-    dict, and the raw exposition text.  Reused by the bench's ``compile``
-    section — the smoke's probe and the gated numbers are the same code.
+    dict, and the raw exposition text.
     """
     import json as _json
 

@@ -738,7 +738,7 @@ def _span_view_arrays(result: "BatchResult", field_ids,
         and pool.workers >= VIEW_POOL_MIN_WORKERS
         and B >= MIN_POOLED_ROWS
     )
-    n_threads = pool.native_threads if pool is not None else 0
+    n_threads = pool.workers if pool is not None else 0
     task_threads = 1 if use_pool else n_threads
     dev = [p for p in pres if p[0] in result.device_views]
     host = [p for p in pres if p[0] not in result.device_views]

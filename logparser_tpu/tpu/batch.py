@@ -695,8 +695,7 @@ class BatchResult:
             else np.empty(0, dtype=np.int64)
         )
         # Lines the host oracle had to visit (device-invalid lines plus
-        # lines whose winning format left requested fields device-unresolved)
-        # — the number bench.py reports as oracle_fraction.
+        # lines whose winning format left requested fields device-unresolved).
         self.oracle_rows = oracle_rows
         self._lines = lines
         self.buf = buf                  # np [B, L] uint8
@@ -887,7 +886,7 @@ class BatchResult:
         from ..native import gather_spans_multi
 
         if not threads and self.assembly_pool is not None:
-            threads = self.assembly_pool.native_threads
+            threads = self.assembly_pool.workers
         B = self.lines_read
         elig = []
         for fid in field_ids:
@@ -3354,9 +3353,7 @@ class TpuBatchParser:
         # Fully-resolved per-(fields, winner) delivery plan: field split,
         # override dict, and the coercion decision (device plan group +
         # setter casts) are all line-invariant — resolving them per VALUE
-        # was ~40% of the rescue stage on top of the raw parses, which is
-        # exactly the kind of drift the bench's rescue-model validation
-        # (combined_rescue config) exists to catch.
+        # was ~40% of the rescue stage on top of the raw parses.
         plan_cache: Dict[Tuple[bool, int], Tuple[list, list]] = {}
 
         def delivery_plan(fields, w, is_invalid):
@@ -3509,8 +3506,8 @@ class TpuBatchParser:
             dirty_rows=dirty_rows, assembly_pool=self.assembly_pool(),
         )
         # Rescue composition for this batch: per-reason routed counts and
-        # the wall seconds the rescue added (the bench's stdout
-        # composition line and the smoke tool read these).
+        # the wall seconds the rescue added (tools/rescue_smoke.py reads
+        # these).
         result.rescue_reasons = rescue_reasons
         result.rescue_wall_s = rescue_wall
         result.escaped_quote_rows = escaped_quote_rows

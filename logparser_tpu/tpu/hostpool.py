@@ -52,26 +52,11 @@ class AssemblyPool:
     pre-pool code path (the thread-count parity suite depends on it).
     """
 
-    def __init__(self, workers: Optional[int] = None,
-                 native_threads: Optional[int] = None):
+    def __init__(self, workers: Optional[int] = None):
         self.workers = max(1, int(workers if workers else default_workers()))
-        # Optional decoupled budget for BATCHED native calls (one call
-        # covering every column).  bench.py's pool=1 baseline uses this
-        # to reproduce the pre-pool serial path exactly: column fan-out
-        # off, native memcpy fan-out at the module default.
-        self._native_threads = native_threads
         self._executor: Optional[ThreadPoolExecutor] = None
         self._closed = False
         self._lock = threading.Lock()
-
-    @property
-    def native_threads(self) -> int:
-        """Thread budget for a BATCHED native call issued outside the
-        pool (one call covering every column): the full worker count
-        unless explicitly overridden."""
-        if self._native_threads is not None:
-            return self._native_threads
-        return self.workers
 
     def _get_executor(self) -> Optional[ThreadPoolExecutor]:
         if self._executor is None:

@@ -1450,10 +1450,11 @@ def compute_units_rows(
 ) -> List[jnp.ndarray]:
     """All formats' packed rows for one batch — the single executor body
     shared by the jnp path (via :func:`units_fn`), the mesh runners, and
-    bench.py.  Every compare and range check is correct under both uint8
-    and int32 inputs: uint8 wraparound "negatives" land >= 230 and int32
-    gives true negatives, and each fails the <= 9 / < 26 digit and letter
-    range checks identically (the timestamp parser digit-checks every
+    the aggregate pass (``analytics/device.py``).  Every compare and
+    range check is correct under both uint8 and int32 inputs: uint8
+    wraparound "negatives" land >= 230 and int32 gives true negatives,
+    and each fails the <= 9 / < 26 digit and letter range checks
+    identically (the timestamp parser digit-checks every
     numeric byte explicitly for exactly this reason)."""
     rows, _ = _units_rows_and_prefixes(units, buf, lengths)
     return rows
@@ -1479,7 +1480,7 @@ def units_fn(units: Sequence[FormatUnit]):
 # (LE-packed, masked beyond len).  The host turns these into Arrow
 # string_view structs with one streaming interleave pass
 # (native lp_views_interleave) instead of re-streaming the whole [B, L]
-# buffer — on the 1-core bench host the byte gather runs at ~6.7 GB/s,
+# buffer — on a 1-core CPU host the byte gather ran at ~6.7 GB/s,
 # on the TPU at HBM speed.  The prefix bytes themselves are extracted
 # inside each unit's pass (span_prefix_words) and only winner-SELECTED
 # here, so view emission adds [B]-shaped selects, not buffer sweeps.

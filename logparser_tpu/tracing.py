@@ -154,7 +154,7 @@ def sample_rate() -> float:
 
 
 def set_sample_rate(rate: float) -> None:
-    """Programmatic override (bench A/B, tests); env is read once at
+    """Programmatic override (tests); env is read once at
     import so sidecars inherit the smoke process's decision."""
     global _SAMPLE_RATE
     _SAMPLE_RATE = min(1.0, max(0.0, float(rate)))

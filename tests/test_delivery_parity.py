@@ -4,10 +4,11 @@ Locks the demand-driven view emission + threaded Arrow assembly work
 against silent drift:
 
 1. ``to_arrow(strings="view")`` and the materialized-strings copy path
-   must be column-for-column equal on every bench config's corpus, with
-   view emission FULL (every span field), DEMAND-PRUNED (a subset of
-   span fields carried by device view rows, the rest host-built), and
-   DISABLED (``emit_views=False`` — all views host-built).
+   must be column-for-column equal on every ``tests/_configs.py``
+   config's corpus, with view emission FULL (every span field),
+   DEMAND-PRUNED (a subset of span fields carried by device view rows,
+   the rest host-built), and DISABLED (``emit_views=False`` — all views
+   host-built).
 2. ``parse_blob`` and ``parse_batch`` over the same payload must produce
    byte-identical Arrow IPC through ``parse_to_ipc``, with the assembly
    pool at 1 worker and >1 workers — delivery output must never depend
@@ -25,18 +26,10 @@ from logparser_tpu.tpu.batch import TpuBatchParser
 from logparser_tpu.tpu.arrow_bridge import parse_to_ipc
 from logparser_tpu.tpu.hostpool import AssemblyPool
 
+from _configs import build_configs
 from _shared_parsers import shared_parser
 
 N_LINES = 384
-
-
-def _bench_configs():
-    """The bench's config table, without importing bench.py at module
-    import time (it resolves GeoIP fixtures and tunes process state)."""
-    import bench
-
-    return {name: (fmt, fields, lines_fn, extra)
-            for name, fmt, fields, lines_fn, extra in bench.build_configs()}
 
 
 FAST_CONFIGS = ("combined", "nginx_uri")
@@ -46,10 +39,10 @@ _EXTRA_CACHE = {}
 
 
 def _config_case(name):
-    cfgs = _bench_configs()
-    if name not in cfgs:
-        pytest.skip(f"bench config {name} unavailable on this host")
-    fmt, fields, lines_fn, extra = cfgs[name]
+    entry = next((c for c in build_configs() if c[0] == name), None)
+    if entry is None:
+        pytest.skip(f"config {name} unavailable on this host")
+    _, fmt, fields, lines_fn, extra = entry
     if extra:
         # extra_dissectors are unhashable: session-cache by config name.
         parser = _EXTRA_CACHE.get(name)
@@ -59,7 +52,7 @@ def _config_case(name):
             )
     else:
         parser = shared_parser(fmt, fields)
-    return parser, lines_fn(N_LINES), fmt, fields
+    return parser, lines_fn(N_LINES), fmt, fields, extra
 
 
 def _assert_view_matches_copy(res):
@@ -73,7 +66,7 @@ def _assert_view_matches_copy(res):
 
 
 def _exercise_config(name):
-    parser, lines, fmt, fields = _config_case(name)
+    parser, lines, fmt, fields, extra = _config_case(name)
     # (a) full view emission — the parse_batch product default.
     res_full = parser.parse_batch(lines)
     _assert_view_matches_copy(res_full)
@@ -98,7 +91,7 @@ def _exercise_config(name):
         if pruned is None:
             pruned = _PRUNED_CACHE[name] = TpuBatchParser(
                 fmt, fields, view_fields=span_fids[:1],
-                extra_dissectors=_bench_configs()[name][3],
+                extra_dissectors=extra,
             )
         res_pruned = pruned.parse_batch(lines)
         assert set(res_pruned.device_views) <= set(span_fids[:1])

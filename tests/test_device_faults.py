@@ -11,7 +11,7 @@ afterwards (no poisoned cached state).
 
 Fast tier: the pure machines (breaker, classifier, chaos hooks, budget
 estimator) + the parity drills on a cheap 2-field format.  Slow tier:
-the parser-survives-fault matrix over the bench configs.
+the parser-survives-fault matrix over the ``tests/_configs.py`` table.
 """
 import time
 
@@ -32,6 +32,8 @@ from logparser_tpu.tpu.device_faults import (
     resolve_deadline,
     run_with_deadline,
 )
+
+from _configs import build_configs
 
 FMT = "%h %u %>s"
 FIELDS = ["IP:connection.client.host", "STRING:request.status.last"]
@@ -356,17 +358,10 @@ def test_artifact_roundtrip_drops_runtime_fault_state(reference):
 
 
 # ---------------------------------------------------------------------------
-# parser-survives-fault across the bench configs (slow tier)
+# parser-survives-fault across the config table (slow tier)
 # ---------------------------------------------------------------------------
 
 N_CONFIG_LINES = 256
-
-
-def _bench_configs():
-    import bench
-
-    return {name: (fmt, fields, lines_fn, extra)
-            for name, fmt, fields, lines_fn, extra in bench.build_configs()}
 
 
 @pytest.mark.slow
@@ -378,11 +373,11 @@ def test_parser_survives_fault_bench_configs(name):
     """After an injected device fault and oracle reroute, the SAME
     TpuBatchParser instance keeps serving parse_batch / parse_blob /
     parse_encoded with byte-identical results — no poisoned cached
-    state, on every bench config."""
-    cfgs = _bench_configs()
-    if name not in cfgs:
-        pytest.skip(f"bench config {name} unavailable on this host")
-    fmt, fields, lines_fn, extra = cfgs[name]
+    state, on every config of the table."""
+    entry = next((c for c in build_configs() if c[0] == name), None)
+    if entry is None:
+        pytest.skip(f"config {name} unavailable on this host")
+    _, fmt, fields, lines_fn, extra = entry
     lines = lines_fn(N_CONFIG_LINES)
     as_bytes = [
         ln.encode("utf-8") if isinstance(ln, str) else ln for ln in lines

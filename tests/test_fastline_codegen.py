@@ -3,7 +3,7 @@
 core/fastline.py compiles the interpreted route closures into exec'd
 per-format source (store-program codegen, round 9).  The contract is
 byte-identical records AND byte-identical failure messages vs the
-interpreted engine — this harness runs every bench format through both
+interpreted engine — this harness runs every config-table format through both
 drivers over hostile corpora, and (when the reference checkout is
 present) the full 3456-line hackers-access.log.
 
@@ -31,7 +31,7 @@ NGINX = (
     '$body_bytes_sent "$http_referer" "$http_user_agent"'
 )
 
-# Every bench.py config's (format, fields) shape, plus the constructs the
+# Every tests/_configs.py config's (format, fields) shape, plus the constructs the
 # compiled path special-cases (URI chain, wildcards, multi-format).
 BENCH_FORMATS = [
     ("combined", HEADLINE_FIELDS),

@@ -31,6 +31,8 @@ from logparser_tpu.service import (
     _ParserCache,
 )
 
+from _configs import build_configs
+
 FIELDS = ["IP:connection.client.host", "STRING:request.status.last"]
 CONFIG = {"log_format": "combined", "fields": FIELDS,
           "timestamp_format": None}
@@ -80,7 +82,7 @@ class TestChipPinning:
             FrontTier(n_sidecars=5)
 
     def test_injected_spawner_is_not_pinned(self, four_chips):
-        # Tests and the bench inject in-process sidecars: they own no
+        # Tests inject in-process sidecars: they own no
         # child process, so no chip is handed out (and 5 > 4 is fine).
         front = FrontTier(n_sidecars=5, spawner=lambda i: None)
         front.shutdown()
@@ -552,11 +554,10 @@ def test_client_fails_fast_on_dead_fleet():
 
 @pytest.mark.slow
 def test_fleet_parity_bench_configs():
-    """Byte parity (acceptance): for every wire-expressible bench
-    config, a session served THROUGH the front returns ARROW payloads
+    """Byte parity (acceptance): for every wire-expressible config of
+    the table, a session served THROUGH the front returns ARROW payloads
     byte-identical to a solo ParseService session — the front is a
     pure relay whatever the routing did."""
-    import bench
     from logparser_tpu.service import ParseService
 
     def payloads_for(corpus):
@@ -594,7 +595,7 @@ def test_fleet_parity_bench_configs():
 
     wire_configs = [
         (name, fmt, fields, lines_fn)
-        for name, fmt, fields, lines_fn, extra in bench.build_configs()
+        for name, fmt, fields, lines_fn, extra in build_configs()
         if not extra
     ]
     for name, fmt, fields, lines_fn in wire_configs:

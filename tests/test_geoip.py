@@ -255,7 +255,7 @@ CHAIN_FIELDS = [
 
 
 def test_geo_chained_through_httpd_parser():
-    """The bench/product shape: geo dissectors chained onto
+    """The product shape: geo dissectors chained onto
     connection.client.host of a real Apache line must DELIVER values
     (a corpus of lookup misses once hid a silent all-null regression)."""
     from logparser_tpu.httpd import HttpdLoglineParser

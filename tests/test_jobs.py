@@ -5,8 +5,7 @@ locks across the inputformat and feeder split paths.
 
 The kill-drill invariant drilled here in-process (JobPolicy.
 stop_after_shards models a crash landing on a commit boundary; the real
-SIGKILL drill lives in tools/job_smoke.py and the bench ``jobs``
-section): interrupted + resumed output must be BYTE-IDENTICAL to an
+SIGKILL drill lives in tools/job_smoke.py): interrupted + resumed output must be BYTE-IDENTICAL to an
 undisturbed run's, with committed shards never re-parsed.
 """
 import json

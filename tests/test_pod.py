@@ -4,8 +4,8 @@ and the pod-level kill-drill invariant — a host lost mid-job is a run of
 uncommitted shards; resume + merge is byte-identical to an undisturbed
 single-host run, with committed shards never re-parsed.
 
-The real-SIGKILL, real-subprocess drill lives in tools/pod_smoke.py and
-the bench ``pod`` section; here the host-loss is modeled in-process
+The real-SIGKILL, real-subprocess drill lives in tools/pod_smoke.py;
+here the host-loss is modeled in-process
 (JobPolicy.stop_after_shards — the same commit-boundary crash model
 test_jobs.py uses one level down).
 """

@@ -22,6 +22,8 @@ from logparser_tpu.service import (
 )
 from logparser_tpu.tools.demolog import generate_combined_lines
 
+from _configs import build_configs
+
 FIELDS = [
     "IP:connection.client.host",
     "TIME.EPOCH:request.receive.time.epoch",
@@ -719,16 +721,6 @@ def _lines_payload(lines):
     return struct.pack(">I", len(lines)) + blob
 
 
-def _bench_wire_configs():
-    """The bench config table, restricted to wire-expressible entries
-    (extra_dissectors cannot ride a CONFIG frame)."""
-    import bench
-
-    return [(name, fmt, fields, lines_fn)
-            for name, fmt, fields, lines_fn, extra in bench.build_configs()
-            if not extra]
-
-
 def _inject_parser(svc, config):
     """Share ONE compiled parser between the solo and coalescing
     services (and across runs, via the session parser cache) — the suite
@@ -745,14 +737,18 @@ def _inject_parser(svc, config):
 @pytest.mark.slow
 def test_cross_session_coalesce_parity_bench_configs():
     """THE coalescing invariant (acceptance): for every wire-expressible
-    bench config, K concurrent sessions pushing interleaved mixed-size
+    config of the table, K concurrent sessions pushing interleaved mixed-size
     requests through the coalescer receive Arrow bytes IDENTICAL to the
     same requests parsed solo — and the drill must actually coalesce
     (>1 session in at least one shared batch)."""
     spb = metrics().histogram("service_coalesced_sessions_per_batch")
     count0, sum0 = spb.count, spb.sum
     sizes_by_session = [(1, 37, 8), (19, 3, 52), (7, 64, 2)]
-    for name, fmt, fields, lines_fn in _bench_wire_configs():
+    # extra_dissectors cannot ride a CONFIG frame: wire-expressible only.
+    wire_configs = [(name, fmt, fields, lines_fn)
+                    for name, fmt, fields, lines_fn, extra in build_configs()
+                    if not extra]
+    for name, fmt, fields, lines_fn in wire_configs:
         corpus = lines_fn(160)
         config = {"log_format": fmt, "fields": list(fields),
                   "timestamp_format": None}

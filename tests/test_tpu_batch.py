@@ -16,6 +16,7 @@ from logparser_tpu.tpu.batch import _CollectingRecord
 from logparser_tpu.tpu.program import compile_device_program
 from logparser_tpu.tpu.runtime import encode_batch, run_program
 
+from _configs import force_escaped_quote_lines, force_rescued_lines
 from _shared_parsers import shared_parser
 
 FIELDS = [
@@ -771,13 +772,11 @@ class TestBatchSlice:
     ]
 
     def _corpus(self):
-        import bench  # the bench's forced-line writers
-
         lines = generate_combined_lines(160, seed=13)
-        lines = bench.force_rescued_lines(lines, 10)  # ~10% oracle-rescued
+        lines = force_rescued_lines(lines, 10)  # ~10% oracle-rescued
         # ...and some device-decoded escaped quotes (round 18), so the
         # slice contract also covers escape-parity-claimed rows.
-        lines = bench.force_escaped_quote_lines(lines, 7)
+        lines = force_escaped_quote_lines(lines, 7)
         lines[5] = "complete garbage"                # definitely-bad row
         return lines
 

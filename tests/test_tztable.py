@@ -143,7 +143,7 @@ def test_device_vs_oracle_zone_corpus():
 
 def test_zone_vocabulary_corpus_stays_on_device():
     """A corpus using only device-vocabulary zones must not touch the
-    oracle at all (the bench gate's oracle_fraction 0.0 contract)."""
+    oracle at all (the oracle_fraction 0.0 contract)."""
     parser = shared_parser(ZONE_FMT, ZONE_FIELDS)
     zones = ["CET", "EST", "UTC", "Europe/Paris", "America/New_York",
              "Asia/Tokyo", "Australia/Sydney", "PST", "GMT"]
