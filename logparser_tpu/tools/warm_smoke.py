@@ -166,6 +166,8 @@ def boot_probe(cache_dir: str, *, lines: Sequence[str],
                 text, "compile_cache_misses_total").values()),
             "cache_errors": sum(_family_values(
                 text, "compile_cache_errors_total").values()),
+            "load_traces": sum(_family_values(
+                text, "compile_cache_load_traces_total").values()),
             "prewarm_shapes": sum(shapes.values()),
             "prewarm_compiled": _labeled(shapes, source="compiled"),
             "prewarm_errors": sum(_family_values(
@@ -226,6 +228,10 @@ def main() -> int:
     if c["deserialize"] < 1:
         problems.append("warm boot deserialized nothing — the "
                         "first request did not come from the cache")
+    if c["load_traces"]:
+        problems.append(
+            f"warm boot traced the program {c['load_traces']:.0f} times "
+            "while loading executables (must be 0)")
     if not warm["prewarm_done"]:
         problems.append(
             "warm boot: background prewarm never completed "

@@ -25,10 +25,11 @@ effect:
   lowering anything (phase=deserialize only).
 
 The pytree structure of the executor's calling convention is FIXED
-((buf [B, L] uint8, lengths [B] int32) -> packed int32 array), so cache
-entries carry only the serialized payload; the in/out treedefs are
-reconstructed from ShapeDtypeStructs at load time (pickling PyTreeDefs is
-not portable across processes).
+((buf [B, L] uint8, lengths [B] int32) -> packed [K, B] int32 array), so
+cache entries carry only the serialized payload; the in/out treedefs are
+rebuilt from that convention at load time (pickling PyTreeDefs is not
+portable across processes), without tracing the program.  A loaded
+executable whose own signature does not fit the convention is refused.
 """
 from __future__ import annotations
 
@@ -358,14 +359,30 @@ _listener_lock = threading.Lock()
 _listening = False
 
 
+# JAX brackets every jaxpr trace with this event: a scalar when it
+# starts, a duration when it ends (jax._src.dispatch.JAXPR_TRACE_EVENT).
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+
 def _on_jax_event(event: str, **_: Any) -> None:
     if event == "/jax/compilation_cache/cache_hits":
         _tls.jax_cache_hits = getattr(_tls, "jax_cache_hits", 0) + 1
 
 
-def _jax_cache_hits() -> int:
-    """JAX persistent-cache hits on THIS thread so far (JAX records them
-    on the compiling thread)."""
+def _on_trace_start(event: str, value: float, **_: Any) -> None:
+    if event == _TRACE_EVENT:
+        _tls.trace_depth = getattr(_tls, "trace_depth", 0) + 1
+
+
+def _on_trace_end(event: str, duration: float, **_: Any) -> None:
+    if event == _TRACE_EVENT:
+        depth = max(getattr(_tls, "trace_depth", 1) - 1, 0)
+        _tls.trace_depth = depth
+        if depth == 0:  # a nested trace is part of its outer one
+            _tls.jax_traces = getattr(_tls, "jax_traces", 0) + 1
+
+
+def _listen() -> None:
     global _listening
     if not _listening:
         with _listener_lock:
@@ -373,8 +390,45 @@ def _jax_cache_hits() -> int:
                 import jax
 
                 jax.monitoring.register_event_listener(_on_jax_event)
+                jax.monitoring.register_scalar_listener(_on_trace_start)
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_trace_end)
                 _listening = True
+
+
+def _jax_cache_hits() -> int:
+    """JAX persistent-cache hits on THIS thread so far (JAX records them
+    on the compiling thread)."""
+    _listen()
     return getattr(_tls, "jax_cache_hits", 0)
+
+
+def _jax_traces() -> int:
+    """Outermost jaxpr traces JAX has made on THIS thread so far: each
+    is one whole program traced (JAX traces on the calling thread)."""
+    _listen()
+    return getattr(_tls, "jax_traces", 0)
+
+
+def _check_fits(exe, avals) -> None:
+    """Raise unless a loaded executable's own signature is the calling
+    convention at this shape: (buf [B, L] uint8, lengths [B] int32) ->
+    one [K, B] int32 array.  Read from the executable, not a trace; a
+    payload with more or other outputs fails here (``out_info`` unflattens
+    them into the one-leaf tree), before anything calls it."""
+    import jax.numpy as jnp
+
+    (buf, lengths), _ = exe.in_avals
+    out = exe.out_info
+    got = [(a.shape, a.dtype) for a in (buf, lengths)]
+    want = [(a.shape, a.dtype) for a in avals]
+    b = avals[1].shape[0]
+    if (got != want or out.dtype != jnp.int32 or len(out.shape) != 2
+            or out.shape[1] != b):
+        raise ValueError(
+            f"executable signature {got} -> {out.shape} {out.dtype} does "
+            f"not fit {want} -> [K, {b}] int32"
+        )
 
 
 def _phase(reg, phase: str, seconds: float) -> None:
@@ -519,14 +573,18 @@ class AotExecutor:
         import jax.tree_util as jtu
 
         t0 = time.perf_counter()
+        traces0 = _jax_traces()
         try:
             avals = self._avals(b, l)
             in_tree = jtu.tree_structure((avals, {}))
-            out_tree = jtu.tree_structure(jax.eval_shape(self._jit, *avals))
+            # One array out, whatever the shape: the output tree is the
+            # calling convention's, so nothing here traces the program.
+            out_tree = jtu.tree_structure(0)
             exe = se.deserialize_and_load(
                 payload, in_tree, out_tree,
                 execution_devices=self._devices or jax.devices()[:1],
             )
+            _check_fits(exe, avals)
         except Exception as exc:
             reg.increment("compile_cache_errors_total",
                           labels={"kind": "deserialize"})
@@ -535,6 +593,9 @@ class AotExecutor:
                 f"{b}x{l}): {type(exc).__name__}: {exc}"
             )
             return None
+        finally:
+            reg.increment("compile_cache_load_traces_total",
+                          _jax_traces() - traces0)
         _phase(reg, "deserialize", time.perf_counter() - t0)
         return exe
 

@@ -508,6 +508,136 @@ def test_corrupted_cache_falls_back_byte_identical(cache_env, drill_lines):
 
 
 # ---------------------------------------------------------------------------
+# the load path: no trace of the program, and a misfit payload is refused
+# ---------------------------------------------------------------------------
+
+
+def _executor(parser, variant):
+    return parser.device_fn() if variant == "plain" else parser.device_views_fn()
+
+
+def _inputs(lines, b):
+    """``lines`` encoded and repeated to ``b`` rows at their line bucket."""
+    import numpy as np
+
+    from logparser_tpu.tpu.runtime import bucket_length, encode_batch
+
+    buf, lengths, _ = encode_batch(
+        lines, line_len=bucket_length(max(map(len, lines))))
+    rows = np.arange(b) % len(lines)
+    return buf[rows], lengths[rows]
+
+
+@pytest.mark.parametrize("variant", ["plain", "views"])
+def test_disk_load_traces_nothing_and_runs_the_cached_executable(
+    variant, cache_env, drill_lines
+):
+    """A disk load builds its calling convention from the shape alone:
+    no trace of the program at the smallest bucket, mid-ladder, or a
+    tiled batch, and the loaded executable's output is byte-identical to
+    the fresh compile's."""
+    import numpy as np
+
+    from logparser_tpu.tpu.batch import TpuBatchParser
+    from logparser_tpu.tpu.pipeline import EXEC_TILE_B
+
+    reg = metrics()
+    compiled = _executor(TpuBatchParser("combined", FIELDS), variant)
+    loaded = _executor(TpuBatchParser("combined", FIELDS), variant)
+    assert compiled.fingerprint == loaded.fingerprint
+    for b in (64, 256, 2 * EXEC_TILE_B):
+        buf, lengths = _inputs(drill_lines, b)
+        shape = tuple(buf.shape)
+        assert compiled.warm(*shape) == "compiled"
+        expected = np.asarray(compiled(buf, lengths))
+        hits0 = reg.get("compile_cache_hits_total")
+        lower0 = reg.get("parser_compile_total", {"phase": "lower"})
+        traces0 = reg.get("compile_cache_load_traces_total")
+        assert loaded.warm(*shape) == "disk", shape
+        assert reg.get("compile_cache_hits_total") == hits0 + 1
+        assert reg.get("parser_compile_total", {"phase": "lower"}) == lower0
+        assert reg.get("compile_cache_load_traces_total") == traces0, shape
+        got = np.asarray(loaded(buf, lengths))
+        assert got.dtype == expected.dtype == np.int32
+        assert got.tobytes() == expected.tobytes(), shape
+
+
+def test_artifact_preload_first_batch_neither_compiles_nor_traces(
+    no_jax_cache, drill_lines
+):
+    """``to_bytes`` -> ``from_bytes`` preloads the embedded executables;
+    the loaded parser's first batch lowers, compiles and traces nothing."""
+    from logparser_tpu.tpu.batch import TpuBatchParser
+    from logparser_tpu.tpu.compile_cache import _jax_traces
+
+    reg = metrics()
+    parser = TpuBatchParser("combined", FIELDS)
+    parser.prewarm(batch_sizes=[64], max_line_len=256)
+    expected = parser.parse_batch(drill_lines)
+    blob = parser.to_bytes()
+    phases = ("lower", "compile", "deserialize")
+    before = {p: reg.get("parser_compile_total", {"phase": p}) for p in phases}
+    load_traces0 = reg.get("compile_cache_load_traces_total")
+    traces0 = _jax_traces()
+    got = TpuBatchParser.from_bytes(blob).parse_batch(drill_lines)
+    after = {p: reg.get("parser_compile_total", {"phase": p}) for p in phases}
+    assert after["lower"] == before["lower"]
+    assert after["compile"] == before["compile"]
+    assert after["deserialize"] > before["deserialize"]
+    assert reg.get("compile_cache_load_traces_total") == load_traces0
+    assert _jax_traces() == traces0
+    assert got.oracle_rows == 0
+    assert got.to_dict() == expected.to_dict()
+
+
+def _misfit_program(kind):
+    import jax.numpy as jnp
+
+    return {
+        # Two arrays out, where the convention has one.
+        "two_outputs": lambda buf, lengths: (lengths, lengths),
+        # One array, but [B] and not [K, B].
+        "rank_one": lambda buf, lengths: lengths * 2,
+        # [B, L] uint8: the right rank, the wrong dtype and columns.
+        "wrong_dtype": lambda buf, lengths: buf + 1,
+    }[kind]
+
+
+@pytest.mark.parametrize("kind", ["two_outputs", "rank_one", "wrong_dtype"])
+def test_payload_whose_output_does_not_fit_is_refused(
+    kind, cache_env, drill_lines
+):
+    """A cached payload that loads but whose signature is not the calling
+    convention is refused inside the load (never called with the wrong
+    tree), counted as a deserialize error, and compiled fresh."""
+    import jax
+    import numpy as np
+    from jax.experimental import serialize_executable as se
+
+    from logparser_tpu.tpu.batch import TpuBatchParser
+
+    reference = TpuBatchParser("combined", FIELDS).device_fn()
+    buf, lengths = _inputs(drill_lines, 64)
+    shape = tuple(buf.shape)
+    reference.warm(*shape)
+    expected = np.asarray(reference(buf, lengths))
+
+    victim = TpuBatchParser("combined", FIELDS).device_fn()
+    misfit = jax.jit(_misfit_program(kind)).lower(
+        *victim._avals(*shape)).compile()
+    payload, _, _ = se.serialize(misfit)
+    victim.cache().put(victim._key(*shape), payload)
+
+    reg = metrics()
+    errors0 = _errors("deserialize")
+    compiles0 = reg.get("parser_compile_total", {"phase": "compile"})
+    assert victim.warm(*shape) == "compiled"
+    assert _errors("deserialize") == errors0 + 1
+    assert reg.get("parser_compile_total", {"phase": "compile"}) == compiles0 + 1
+    assert np.asarray(victim(buf, lengths)).tobytes() == expected.tobytes()
+
+
+# ---------------------------------------------------------------------------
 # round-21 device residuals: protocol split + timezone string table
 # ---------------------------------------------------------------------------
 
